@@ -42,7 +42,7 @@ def _run_arm(max_batch: int, n: int, timeout: float):
     """One shard, one client, N queued requests; returns tasks/sec."""
     from repro.core.queues import ColmenaQueues
     from repro.serving.shard import (InferenceClient, send_shard_stop,
-                                     start_inference_shard)
+                                     start_inference_shard, wait_for_exit)
     spec = _spec(max_batch)
     q = ColmenaQueues([], backend="proc", lease_timeout=60.0,
                       serve_spec=spec)
@@ -76,9 +76,8 @@ def _run_arm(max_batch: int, n: int, timeout: float):
         except (ConnectionError, OSError):
             pass
         if proc is not None:
-            proc.join(timeout=10)
-            if proc.is_alive():
-                proc.terminate()
+            # the next arm's shard needs the device this one holds
+            wait_for_exit(proc)
         q.shutdown()
 
 

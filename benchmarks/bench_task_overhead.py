@@ -185,13 +185,6 @@ def run_device_array_bench(mib: int = 8, reps: int = 5):
 
     from repro.core.transport.shards import ShardedValueServer
 
-    try:
-        import jax.numpy as jnp
-        arr = jnp.arange(mib << 18, dtype=jnp.float32)     # mib MiB
-        kind = "jax"
-    except Exception:                   # pragma: no cover - jax baked in
-        arr = np.arange(mib << 18, dtype=np.float32)
-        kind = "np"
     nbytes = mib << 20
 
     def roundtrip(client):
@@ -203,8 +196,12 @@ def run_device_array_bench(mib: int = 8, reps: int = 5):
         client.delete(key)
         return dt * 1e3
 
+    # fork the shard before this process touches the device: a forked
+    # child inherits a runtime it cannot use
     vs = ShardedValueServer(1)
     try:
+        import jax.numpy as jnp
+        arr = jnp.arange(mib << 18, dtype=jnp.float32)     # mib MiB
         plain = ShardedValueServer.connect([a for _, a in vs._members],
                                            array_codec=False)
         roundtrip(vs), roundtrip(plain)            # warmup both arms
@@ -215,7 +212,7 @@ def run_device_array_bench(mib: int = 8, reps: int = 5):
             t_pickle = tp if t_pickle is None else min(t_pickle, tp)
     finally:
         vs.shutdown()
-    note = f"{mib}MiB {kind} array, best of {reps}"
+    note = f"{mib}MiB jax array, best of {reps}"
     return [("vs_device_array_roundtrip_ms", t_codec, note),
             ("vs_device_array_roundtrip_pickle_ms", t_pickle, note),
             ("vs_device_array_codec_speedup", t_pickle / t_codec,

@@ -3,12 +3,15 @@
     PYTHONPATH=src python -m benchmarks.run [--full]
 
 Prints ``name,value,derived`` CSV.  --full uses paper-scale parameters
-(slower); the default sizes finish in a few minutes on CPU.
+(slower); the default sizes finish in a few minutes on CPU.  A suite
+that raises prints an ``ERROR`` row and its traceback, the remaining
+suites still run, and the command exits non-zero.
 """
 from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -32,12 +35,15 @@ def main() -> None:
         ("roofline (dry-run)", bench_roofline.run, {}),
     ]
     print("name,value,derived")
+    failed = []
     for title, fn, kw in suites:
         t0 = time.perf_counter()
         try:
             rows = fn(**kw)
         except Exception as e:                     # noqa: BLE001
+            traceback.print_exc()
             print(f"{title},ERROR,{e!r}")
+            failed.append(title)
             continue
         for name, val, extra in rows:
             if isinstance(val, float):
@@ -45,6 +51,8 @@ def main() -> None:
             else:
                 print(f"{name},{val},{extra}")
         print(f"# {title} done in {time.perf_counter()-t0:.1f}s")
+    if failed:
+        sys.exit(f"{len(failed)} suite(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -214,13 +214,17 @@ class ClusterLauncher:
         return entry
 
     def _start_infer_shard(self, host: str, idx: int) -> dict:
-        from repro.serving.shard import start_inference_shard
+        from repro.serving.shard import chip_env, start_inference_shard
+        env = self._host_env(host)
+        if self.spec.host(host).inference_shards > 1:
+            # one chip per shard; the host's explicit env still wins
+            env = {**chip_env(idx), **env}
         p = start_inference_shard(
             self._addresses[self.spec.local_broker_of(host)],
             self.serve_spec,
             lease_timeout=self.spec.lease_timeout,
             identity=f"infer@{host}:{idx}",
-            env=self._host_env(host) or None)
+            env=env or None)
         entry = {"host": host, "idx": idx, "proc": p}
         self._infer_shards.append(entry)
         return entry

@@ -37,13 +37,9 @@ _TCMALLOC_CANDIDATES = (
 )
 
 
-def perf_env_vars(n_local_workers: int) -> Dict[str, str]:
+def perf_env_vars() -> Dict[str, str]:
     """The HPC launcher environment idioms, as data:
 
-    - ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` partitions
-      the host CPU into one XLA device per local worker, so jax-based
-      methods sharing a node each get a device instead of fighting over
-      one.
     - tcmalloc via ``LD_PRELOAD`` (only when the library is actually
       installed), with its large-alloc report threshold raised so
       multi-GB device buffers don't spam stderr.
@@ -52,14 +48,10 @@ def perf_env_vars(n_local_workers: int) -> Dict[str, str]:
 
     ``LD_PRELOAD`` takes effect on *exec* -- it reaches agents launched
     over ssh (fresh interpreter) but not fork-only simulated hosts,
-    which inherit the launcher's already-loaded allocator.  The XLA and
-    logging variables just need to be set before the first jax/XLA
-    import and work on both paths."""
-    env = {
-        "XLA_FLAGS": ("--xla_force_host_platform_device_count="
-                      f"{max(n_local_workers, 1)}"),
-        "TF_CPP_MIN_LOG_LEVEL": "4",
-    }
+    which inherit the launcher's already-loaded allocator.  The logging
+    variable just needs to be set before the first jax/XLA import and
+    works on both paths."""
+    env = {"TF_CPP_MIN_LOG_LEVEL": "4"}
     for so in _TCMALLOC_CANDIDATES:
         if os.path.exists(so):
             env["LD_PRELOAD"] = so
@@ -93,7 +85,8 @@ class HostSpec:
     broker: bool = True
     pools: Dict[str, int] = field(default_factory=dict)  # topic -> workers
     vs_shards: int = 0
-    inference_shards: int = 0    # continuous-batching serving processes
+    inference_shards: int = 0    # continuous-batching serving processes;
+                                 # with several, shard i gets chip i
     thinker: bool = False
     address: Optional[tuple] = None
     ssh: Optional[str] = None
@@ -122,10 +115,9 @@ class ClusterSpec:
         the first such host's broker so serving traffic stays on-host,
         and ``topics()`` registers it for connecting clients.
         perf_env: apply the launcher performance-environment idioms
-        (``perf_env_vars``: per-worker XLA host devices, tcmalloc when
-        installed, quiet XLA logging) to every host's agent and
-        inference shards.  Off by default; ``HostSpec.env`` entries
-        override it per host either way."""
+        (``perf_env_vars``: tcmalloc when installed, quiet XLA logging)
+        to every host's agent and inference shards.  Off by default;
+        ``HostSpec.env`` entries override it per host either way."""
         if not hosts:
             raise ValueError("a ClusterSpec needs at least one host")
         if vs_replicas < 1:
@@ -222,15 +214,13 @@ class ClusterSpec:
 
     def env_for(self, name: str) -> Dict[str, str]:
         """The environment the launcher applies to ``name``'s agent and
-        inference shards: the perf-env idioms (when ``perf_env`` is on,
-        sized to the host's own worker + shard count) overlaid with the
-        host's explicit ``env`` map.  Empty when neither is set, so the
-        default path touches nothing."""
+        inference shards: the perf-env idioms (when ``perf_env`` is on)
+        overlaid with the host's explicit ``env`` map.  Empty when
+        neither is set, so the default path touches nothing."""
         h = self.host(name)
         env: Dict[str, str] = {}
         if self.perf_env:
-            n = sum(h.pools.values()) + h.inference_shards
-            env.update(perf_env_vars(n))
+            env.update(perf_env_vars())
         env.update(h.env)
         return env
 

@@ -58,6 +58,9 @@ class Result:
     input_size: int = 0
     output_size: int = 0
     worker: Optional[str] = None
+    #: the device that computed ``value`` (inference shards: platform,
+    #: ``device_kind`` and id as JAX reports them); None elsewhere
+    device: Optional[dict] = None
 
     @property
     def task_runtime(self) -> float:

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -87,17 +89,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "window", "softcap", "q_offset",
-                     "block_q", "block_k", "interpret"))
+                     "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None, q_offset: int = 0,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
-    """q (B,Sq,H,hd); k/v (B,Sk,KVH,hd) -> (B,Sq,H,hd).
-
-    interpret=True executes the kernel body in Python on CPU (the validation
-    mode for this container); on a real TPU pass interpret=False.
-    """
+                    block_q: int = 128, block_k: int = 128):
+    """q (B,Sq,H,hd); k/v (B,Sk,KVH,hd) -> (B,Sq,H,hd)."""
     B, Sq, H, hd = q.shape
     _, Sk, KVH, _ = k.shape
     G = H // KVH
@@ -118,8 +115,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
         _kernel, scale=hd ** -0.5, causal=causal, window=window,
         softcap=softcap, q_offset=q_offset, bq=bq, bk=bk, nk=nk)
 
-    out = pl.pallas_call(
-        kernel,
+    out = pallas_call(
+        kernel, qr, kr, vr,
         grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, hd), lambda bh, iq, ik: (bh, iq, 0)),
@@ -133,6 +130,5 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom l
             pltpu.VMEM((bq, hd), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
-    )(qr, kr, vr)
+    )
     return jnp.moveaxis(out.reshape(B, H, Sq, hd), 1, 2)

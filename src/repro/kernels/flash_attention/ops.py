@@ -6,9 +6,6 @@ import jax
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.flash_attention import flash_attention  # noqa: F401
 
-# interpret=True is the default inside flash_attention (CPU validation);
-# a TPU deployment calls flash_attention(..., interpret=False).
-
 attention_reference = ref.attention_reference
 
 

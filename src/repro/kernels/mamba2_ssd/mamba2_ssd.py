@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_call
+
 
 def _segsum(log_a):
     """(Q,) -> (Q, Q) lower-tri pairwise sums: out[i,j]=sum_{j<s<=i} log_a[s]."""
@@ -70,9 +72,8 @@ def _kernel(x_ref, la_ref, b_ref, c_ref, s0_ref, y_ref, sout_ref, s_scr,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("chunk", "interpret"))
-def ssd_pallas(x, log_a, b, c, initial_state=None, *, chunk: int = 128,
-               interpret: bool = True):
+                   static_argnames=("chunk",))
+def ssd_pallas(x, log_a, b, c, initial_state=None, *, chunk: int = 128):
     """Same contract as ref.ssd_chunked. x (B,L,H,P); log_a (B,L,H);
     b/c (B,L,G,N); state (B,H,P,N)."""
     B, L, H, P = x.shape
@@ -86,8 +87,8 @@ def ssd_pallas(x, log_a, b, c, initial_state=None, *, chunk: int = 128,
     group = (lambda h: h * G // H) if G != H else (lambda h: h)
 
     kernel = functools.partial(_kernel, nc=nc)
-    y, s_out = pl.pallas_call(
-        kernel,
+    y, s_out = pallas_call(
+        kernel, x, log_a, b, c, initial_state,
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, Q, 1, P), lambda ib, ih, ic: (ib, ic, ih, 0)),
@@ -107,6 +108,5 @@ def ssd_pallas(x, log_a, b, c, initial_state=None, *, chunk: int = 128,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        interpret=interpret,
-    )(x, log_a, b, c, initial_state)
+    )
     return y, s_out

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_call
+
 
 def _kernel(x_ref, w_ref, o_ref, acc_scr, *, nd: int):
     kd = pl.program_id(3)
@@ -38,9 +40,8 @@ def _kernel(x_ref, w_ref, o_ref, acc_scr, *, nd: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f",
-                                             "block_d", "interpret"))
-def gmm(xe, w, *, block_c: int = 128, block_f: int = 128,
-        block_d: int = 512, interpret: bool = True):
+                                             "block_d"))
+def gmm(xe, w, *, block_c: int = 128, block_f: int = 128, block_d: int = 512):
     """xe (E, C, D) @ w (E, D, F) -> (E, C, F)."""
     E, C, D = xe.shape
     _, _, F = w.shape
@@ -49,8 +50,8 @@ def gmm(xe, w, *, block_c: int = 128, block_f: int = 128,
     nd = D // bd
 
     kernel = functools.partial(_kernel, nd=nd)
-    return pl.pallas_call(
-        kernel,
+    return pallas_call(
+        kernel, xe, w,
         grid=(E, C // bc, F // bf, nd),
         in_specs=[
             pl.BlockSpec((1, bc, bd), lambda e, ic, jf, kd: (e, ic, kd)),
@@ -60,5 +61,4 @@ def gmm(xe, w, *, block_c: int = 128, block_f: int = 128,
                                lambda e, ic, jf, kd: (e, ic, jf)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), xe.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        interpret=interpret,
-    )(xe, w)
+    )

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_call
+
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
             s_scr, *, nc: int, Q: int):
@@ -64,9 +66,8 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
         sout_ref[0, 0] = s_scr[...]
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_pallas(r, k, v, log_w, u, initial_state=None, *, chunk: int = 64,
-                interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def wkv6_pallas(r, k, v, log_w, u, initial_state=None, *, chunk: int = 64):
     """Same contract as ref.wkv6_chunked. r/k/log_w (B,L,H,K); v (B,L,H,V);
     u (H,K); state (B,H,K,V)."""
     B, L, H, K = r.shape
@@ -78,8 +79,8 @@ def wkv6_pallas(r, k, v, log_w, u, initial_state=None, *, chunk: int = 64,
         initial_state = jnp.zeros((B, H, K, V), jnp.float32)
 
     kernel = functools.partial(_kernel, nc=nc, Q=Q)
-    y, s_out = pl.pallas_call(
-        kernel,
+    y, s_out = pallas_call(
+        kernel, r, k, v, log_w, u, initial_state,
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, Q, 1, K), lambda ib, ih, ic: (ib, ic, ih, 0)),
@@ -98,6 +99,5 @@ def wkv6_pallas(r, k, v, log_w, u, initial_state=None, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        interpret=interpret,
-    )(r, k, v, log_w, u, initial_state)
+    )
     return y, s_out

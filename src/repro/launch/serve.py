@@ -13,6 +13,7 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.models import api
 from repro.serving.engine import Engine
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -26,6 +27,7 @@ def main():
                     help="number of batched request rounds")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=not args.full)
     params = api.init_params(cfg, jax.random.PRNGKey(args.seed))

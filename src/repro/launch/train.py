@@ -22,6 +22,7 @@ from repro.configs.base import ShardingConfig, TrainConfig, get_config
 from repro.data.loader import PrefetchLoader
 from repro.data.tokens import make_batch
 from repro.launch import steps
+from repro.utils.compile_cache import use_compile_cache
 
 
 def train(arch: str, *, reduced: bool = True, steps_total: int = 50,
@@ -99,6 +100,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
     _, losses = train(args.arch, reduced=not args.full,
                       steps_total=args.steps, batch=args.batch, seq=args.seq,
                       lr=args.lr, microbatches=args.microbatches,

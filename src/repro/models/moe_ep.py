@@ -26,7 +26,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed import axisenv
-from repro.distributed.compat import shard_map
 from repro.models.mlp import _act
 
 
@@ -157,7 +156,7 @@ def moe_ep_a2a(params, x, cfg: ModelConfig, mesh, batch_axes):
         aux = jax.lax.pmean(aux, all_axes)                 # global mean
         return y.reshape(b_loc, S_loc, D).astype(x_loc.dtype), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(x, params["router"], params["wi_gate"], params["wi_up"], params["wo"])

@@ -64,6 +64,14 @@ class Engine:
                       "tokens_out": 0, "wall": 0.0, "compile_wall": 0.0,
                       "warm_tokens": 0}
 
+    def device_info(self) -> dict:
+        """The device holding this engine's weights, as JAX reports it."""
+        (dev,) = jax.tree.leaves(self.params)[0].devices()
+        coords = getattr(dev, "coords", None)       # TPU only
+        return {"platform": dev.platform, "kind": dev.device_kind,
+                "id": dev.id,
+                "coords": None if coords is None else list(coords)}
+
     # -- stepwise API (continuous batching) ---------------------------------
 
     def prefill_batch(self, tokens: np.ndarray, *,

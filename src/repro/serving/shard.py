@@ -38,6 +38,7 @@ from __future__ import annotations
 import multiprocessing
 import os
 import signal
+import sys
 import threading
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence
@@ -129,6 +130,11 @@ class ServeLoop:
         self.spec = spec
         self.identity = identity
         self.engine = engine if engine is not None else spec.make_engine()
+        # where the engine's weights live, stamped on every result (stub
+        # engines in tests run on no device and report None)
+        device_info = getattr(self.engine, "device_info", None)
+        self.device = (None if device_info is None
+                       else {**device_info(), "chip_files": chip_files()})
         self.requests = transport.channel(spec.topic, "requests")
         self.results = transport.channel(spec.topic, "results")
         self.batcher = MicroBatcher(
@@ -266,7 +272,8 @@ class ServeLoop:
         t_fin = now()
         result = msg.Result(task_id=req.task_id, topic=self.spec.topic,
                             method="infer", success=success, value=value,
-                            error=error, worker=self.identity)
+                            error=error, worker=self.identity,
+                            device=self.device)
         data = msg.serialize(result)
         meta = {"output_size": len(data), "task_id": req.task_id}
         if req.meta.get("trace"):
@@ -396,12 +403,15 @@ def inference_shard_main(address: tuple, spec: ServeSpec, *,
     """Entry point of a forked shard process: dial the broker that homes
     the serve topic, build the engine (first jax import happens here,
     inside the child), serve until a stop envelope or SIGTERM.  ``env``
-    entries (``ClusterSpec.env_for``) are applied before the engine
-    build so XLA-style variables precede the first jax import."""
+    entries (``ClusterSpec.env_for``, ``chip_env``) are applied before
+    the engine build so XLA- and TPU-runtime variables precede the first
+    jax import."""
     from repro.core.transport.proc import ProcTransport
+    from repro.utils.compile_cache import use_compile_cache
 
     if env:
         os.environ.update(env)
+    use_compile_cache()
     stop = threading.Event()
 
     def _sigterm(signum, frame):
@@ -427,19 +437,83 @@ def inference_shard_main(address: tuple, spec: ServeSpec, *,
     os._exit(0)
 
 
+def chip_env(index: int) -> dict:
+    """The TPU-runtime environment that confines shard ``index`` of a
+    host to chip ``index``.  Without it every shard of a multi-chip host
+    opens every chip, and the second one fails on libtpu's lock.  A
+    chip-per-process bound below the host's chip count is what lets
+    several processes load libtpu at once; each such process is its own
+    one-chip slice and needs its own runtime port.  The variables mean
+    nothing to the CPU backend."""
+    return {"TPU_VISIBLE_CHIPS": str(index),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(8476 + index)}
+
+
+def chip_files() -> List[str]:
+    """The accelerator device files this process holds open: which
+    physical chip it drives, where the runtime numbers the only chip of
+    every one-chip process 0.  Empty off an accelerator."""
+    out = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:                     # closed since the listing
+            continue
+        if (target.startswith(("/dev/accel", "/dev/vfio/"))
+                and target != "/dev/vfio/vfio"):
+            out.add(target)
+    return sorted(out)
+
+
+def _refuse_if_holding_accelerator() -> None:
+    """A chip belongs to one process at a time, and a forked child
+    inherits its parent's runtime state: a parent that has initialised
+    an accelerator backend holds the chip, and the shard would then fail
+    or hang.  A CPU backend holds no chip (CPU-only test processes fork
+    stub shards)."""
+    if "jax" not in sys.modules:
+        return
+    import jax
+    from jax._src import xla_bridge
+    if (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "cpu"):
+        raise RuntimeError(
+            "start_inference_shard: this process has already initialised"
+            f" the JAX {jax.default_backend()!r} backend and so holds the"
+            " chip; fork shards before the parent's first JAX computation"
+            " (or keep the parent off JAX)")
+
+
 def start_inference_shard(address: tuple, spec: ServeSpec, *,
                           lease_timeout: float = 30.0,
                           identity: str = "infer-shard",
                           env: Optional[dict] = None):
     """Fork one shard process against ``address`` (a broker reachable
     with the serve topic).  Used by the cluster launcher, the serving
-    bench, and the chaos tests."""
+    bench, and the chaos tests.  Refuses to fork from a process that
+    holds an accelerator."""
+    _refuse_if_holding_accelerator()
     p = _mp.Process(target=inference_shard_main, args=(address, spec),
                     kwargs={"lease_timeout": lease_timeout,
                             "identity": identity, "env": env},
                     daemon=True, name=f"colmena-{identity}")
     p.start()
     return p
+
+
+def wait_for_exit(proc, timeout: float = 10.0) -> None:
+    """Join a shard process, escalating to SIGTERM and then SIGKILL.
+    Returns only once the process has exited, so a chip it held is free
+    for the next process."""
+    proc.join(timeout)
+    if proc.is_alive():
+        proc.terminate()
+        proc.join(timeout)
+    if proc.is_alive():
+        proc.kill()
+        proc.join()
 
 
 def send_shard_stop(transport: Transport, topic: str, n: int = 1) -> None:
@@ -460,6 +534,9 @@ class InferenceClient:
     def __init__(self, queues, *, topic: Optional[str] = None):
         self.queues = queues
         self.topic = topic or queues.serve_topic
+        # drained results not yet handed out: a gather that timed out
+        # keeps what it received, so a retry loses nothing
+        self._got: dict = {}
 
     def submit(self, prompts: Sequence[Sequence[int]], *,
                max_new: Optional[int] = None) -> List[str]:
@@ -470,9 +547,11 @@ class InferenceClient:
     def gather(self, task_ids: Sequence[str], *,
                timeout: Optional[float] = None) -> List[msg.Result]:
         """Block until every id has a result; returns them in the order
-        of ``task_ids`` regardless of completion order."""
+        of ``task_ids`` regardless of completion order.  On
+        ``TimeoutError`` the results received so far stay with the
+        client for the next gather."""
         want = set(task_ids)
-        got: dict = {}
+        got = self._got
         deadline = None if timeout is None else now() + timeout
         while want - set(got):
             remaining = None
@@ -485,7 +564,7 @@ class InferenceClient:
             for r in self.queues.get_results(self.topic, max_n=64,
                                              timeout=remaining):
                 got[r.task_id] = r
-        return [got[t] for t in task_ids]
+        return [got.pop(t) for t in task_ids]
 
     def infer(self, prompts: Sequence[Sequence[int]], *,
               max_new: Optional[int] = None,

@@ -561,3 +561,38 @@ def test_cluster_campaign_kill9_resume_with_value_server(tmp_path):
             assert q2.active_count == 0
         finally:
             q2.shutdown()
+
+
+class _NoProcess:
+    """Stands in for a forked shard that has already exited."""
+
+    def is_alive(self):
+        return False
+
+    def join(self, timeout=None):
+        pass
+
+
+def test_launcher_gives_each_inference_shard_of_a_host_its_own_chip(
+        monkeypatch):
+    from repro.serving import shard
+    from repro.serving.shard import ServeSpec
+    envs = []
+
+    def record(address, spec, **kw):
+        envs.append(kw["env"] or {})
+        return _NoProcess()
+
+    monkeypatch.setattr(shard, "start_inference_shard", record)
+    spec = ClusterSpec([
+        HostSpec("h0", inference_shards=2, thinker=True,
+                 env={"JAX_PLATFORMS": "cpu"}),
+        HostSpec("h1", inference_shards=1),
+    ])
+    with ClusterLauncher(spec, serve_spec=ServeSpec()):
+        pass
+    assert [e.get("TPU_VISIBLE_CHIPS") for e in envs] == ["0", "1", None]
+    assert envs[0]["TPU_PROCESS_PORT"] != envs[1]["TPU_PROCESS_PORT"]
+    assert all(e.get("JAX_PLATFORMS") == "cpu" for e in envs[:2])
+    # a host's only shard keeps every chip of the host
+    assert envs[2] == {}

@@ -11,13 +11,14 @@ import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import sys; sys.path.insert(0, 'src')
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs.base import get_config, SHAPES, ShardingConfig, TrainConfig
 from repro.distributed import axisenv, sharding as shd
 from repro.models import api, moe
 from repro.launch import steps
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 
 # 1. shard_map EP MoE == GSPMD dropping path (no drops)
 cfg = get_config('kimi-k2-1t-a32b', reduced=True).replace(

@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret=True."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpreted off a TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

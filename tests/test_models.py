@@ -124,3 +124,22 @@ def test_param_count_analytic_close():
         predicted = param_count(cfg)
         assert abs(actual - predicted) / actual < 0.05, \
             (arch, actual, predicted)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 0.05)])
+def test_prefill_matches_float32_reference(dtype, tol):
+    """The served prefill's last-position logits against the plain
+    float32 reference forward: to rounding in float32, and within the
+    bound chip_smoke.py holds the full-width bf16 model to (as a share
+    of the largest reference logit)."""
+    from repro.models import reference
+    cfg = get_config("internlm2-1.8b", reduced=True).replace(
+        param_dtype=dtype, compute_dtype=dtype)
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0,
+                              cfg.vocab_size)
+    logits, _ = api.prefill(params, cfg, {"tokens": toks})
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(reference.last_logits(params, cfg, toks))
+    err = np.max(np.abs(np.asarray(logits, np.float32) - ref))
+    assert err <= tol * np.max(np.abs(ref)), err

@@ -308,6 +308,39 @@ def test_serve_loop_continuous_admission():
     assert loop.stats["decode_steps"] >= 38
 
 
+class _DeviceStubEngine(_StubEngine):
+    def device_info(self):
+        return {"platform": "stub", "kind": "stub", "id": 3, "coords": None}
+
+
+def test_results_name_their_device_and_gather_keeps_partial_results():
+    """Every result names the device that computed it, and a gather that
+    times out keeps what it already drained for the next gather."""
+    spec = ServeSpec(engine_factory=_stub_factory, max_batch=4,
+                     prompt_buckets=(8,), max_batch_delay_ms=5.0)
+    q, loop, th = _local_shard(spec, engine=_DeviceStubEngine())
+    try:
+        client = InferenceClient(q)
+        (tid,) = client.submit([[1, 2]], max_new=2)
+        with pytest.raises(TimeoutError):
+            client.gather([tid, "never-submitted"], timeout=1.0)
+        (r,) = client.gather([tid], timeout=5.0)
+        assert r.success and r.value == [3, 4]
+        assert r.device["id"] == 3 and r.device["chip_files"] == []
+    finally:
+        _stop_local(q, spec, th)
+
+
+def test_start_inference_shard_refuses_a_process_holding_an_accelerator(
+        monkeypatch):
+    import jax
+    jax.devices()                           # this process's backend is up
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        start_inference_shard(("tcp", "127.0.0.1", 9),
+                              ServeSpec(engine_factory=_stub_factory))
+
+
 # ---------------------------------------------------------------------------
 # synapp steering: the proxy-model scorer routed through a shard
 # ---------------------------------------------------------------------------

@@ -192,3 +192,20 @@ def test_prefetch_loader_order():
     got = [next(loader) for _ in range(3)]
     loader.close()
     assert got == [(3, 30), (4, 40), (5, 50)]
+
+
+def test_compile_cache_follows_the_env_or_the_checkout(monkeypatch):
+    from repro.utils import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/from/the/env")
+        assert compile_cache.use_compile_cache() == "/from/the/env"
+        assert jax.config.jax_compilation_cache_dir == before  # set nothing
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        path = compile_cache.use_compile_cache()
+        assert path == str(compile_cache.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        # a fixed directory inside the checkout, beside pyproject.toml
+        assert (compile_cache.DEFAULT_DIR.parent / "pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
