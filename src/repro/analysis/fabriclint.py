@@ -27,10 +27,11 @@ mechanical check over ``src/repro/core/**``:
 - **frame-header-hygiene** -- wire headers are plain dicts with string
   keys and primitive values; envelope payload bytes ride the frame body
   and are relayed verbatim, never re-pickled (single-pickle-per-hop).
-- **span-name-registry** -- every ``obs.span``/``obs.counter``/... call
-  in fabric code names a literal declared in
+- **span-name-registry** -- every ``obs.span``/``obs.counter``/
+  ``obs.region``/... call in fabric code names a literal declared in
   ``repro.observability.names``; an undeclared or dynamic name silently
-  fragments the merged timeline and the metrics rollup.
+  fragments the merged timeline, the metrics rollup and the profiler's
+  regions.
 
 False positives are suppressed in place with a justified pragma::
 
@@ -52,7 +53,7 @@ from pathlib import Path
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Set
 
 from repro.analysis.idempotent_ops import IDEMPOTENT_OPS
-from repro.observability.names import METRIC_NAMES, SPAN_NAMES
+from repro.observability.names import METRIC_NAMES, REGION_NAMES, SPAN_NAMES
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
 # the fabric's concurrency surface: the dispatch core plus the serving
@@ -638,6 +639,7 @@ _OBS_NAME_SITES = {
     "gauge": (0, METRIC_NAMES, "METRIC_NAMES"),
     "histo": (0, METRIC_NAMES, "METRIC_NAMES"),
     "observe": (0, METRIC_NAMES, "METRIC_NAMES"),
+    "region": (0, REGION_NAMES, "REGION_NAMES"),
 }
 
 
@@ -648,9 +650,9 @@ def pass_span_name_registry(ctx: FileCtx) -> List[Finding]:
     A typo'd or dynamically built name doesn't error -- it just
     fragments the timeline into series nobody aggregates.  Every
     ``obs.span``/``obs.instant``/``obs.counter``/``obs.gauge``/
-    ``obs.histo``/``obs.observe`` call site (the ``from repro import
-    observability as obs`` convention) must therefore name a literal
-    declared in ``repro.observability.names``."""
+    ``obs.histo``/``obs.observe``/``obs.region`` call site (the ``from
+    repro import observability as obs`` convention) must therefore name
+    a literal declared in ``repro.observability.names``."""
     out = []
     for node in ast.walk(ctx.tree):
         if not (isinstance(node, ast.Call)

@@ -43,7 +43,6 @@ def main():
               f"sample tail: {out[0, -8:].tolist()}")
     print(f"steady-state throughput: {engine.throughput():.1f} tok/s "
           f"(prefills={engine.stats['prefill_calls']}, "
-          f"decode_steps={engine.stats['decode_steps']}, "
           f"compile {engine.stats['compile_wall']:.2f}s excluded)")
 
 

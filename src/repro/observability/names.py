@@ -1,4 +1,5 @@
-"""Single-source registry of every span and metric name the fabric emits.
+"""Single-source registry of every span, metric and profiler-region name
+the fabric emits.
 
 Dashboards, the report's Fig.-5 decomposition table, and the chaos
 trace-continuity tests all key on these names.  Instrumentation in
@@ -73,4 +74,27 @@ METRIC_NAMES = {
                             "the task was already cancelled",
 }
 
-__all__ = ["SPAN_NAMES", "METRIC_NAMES"]
+# profiler region name -> one-line description.  ``obs.region`` records
+# these only while a jax.profiler session runs in the process; the
+# ``serve.*`` regions tile a serve-loop turn and never nest, the
+# ``engine.*`` regions nest inside them.  ``t`` is perf_counter_ns() at
+# entry: the anchor that maps jsonl span times onto the trace's clock.
+REGION_NAMES = {
+    # -- inference shard serve loop (serving/shard.py) -------------------
+    "serve.park": "get_batch, no group active, none pending; arg t",
+    "serve.poll": "get_batch holding work (ADMIT_POLL or deadline); arg t",
+    "serve.intake": "drained envelopes into the batcher, lease bookkeeping",
+    "serve.admit": "one micro-batch prefilled; args B, N, S, R",
+    "serve.step": "one group's decode step; args B, N (live rows), P (pos)",
+    "serve.publish": "one burst of result publishes; arg n",
+    "serve.compact": "a group's compaction gather",
+    # -- engine calls (serving/engine.py) --------------------------------
+    "engine.prefill": "Engine.prefill_batch",
+    "engine.grow_cache": "prefill: cache grown to the reserve (eager pad)",
+    "engine.decode": "Engine.decode_batch",
+    "engine.dispatch": "decode: the jitted step and its argmax dispatched",
+    "engine.readback": "prefill or decode: the tokens read to the host",
+    "engine.gather": "Engine.gather_rows",
+}
+
+__all__ = ["SPAN_NAMES", "METRIC_NAMES", "REGION_NAMES"]

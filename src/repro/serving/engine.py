@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import observability as obs
 from repro.configs.base import ModelConfig
 from repro.models import api
 
@@ -55,13 +56,19 @@ class Engine:
         self.cfg = cfg
         self.params = params
         self.max_new = max_new
-        self._prefill = jax.jit(
-            lambda p, b: api.prefill(p, cfg, b))
-        self._decode = jax.jit(
-            lambda p, c, t, n: api.decode_step(p, cfg, c, t, n))
+
+        # named, so a profile's XLA modules read jit_prefill and
+        # jit_decode_step
+        def prefill(params, batch):
+            return api.prefill(params, cfg, batch)
+
+        def decode_step(params, cache, cur, pos):
+            return api.decode_step(params, cfg, cache, cur, pos)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step)
         self._warm: set = set()   # (B, S, max_new) shapes already compiled
-        self.stats = {"prefill_calls": 0, "decode_steps": 0,
-                      "tokens_out": 0, "wall": 0.0, "compile_wall": 0.0,
+        self.stats = {"prefill_calls": 0, "wall": 0.0, "compile_wall": 0.0,
                       "warm_tokens": 0}
 
     def device_info(self) -> dict:
@@ -83,19 +90,21 @@ class Engine:
         GenState positioned for decode)."""
         B, S = tokens.shape
         reserve = reserve if reserve is not None else S + self.max_new
-        batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
-        if self.cfg.is_encdec:
-            if frames is None:
-                frames = np.zeros((B, S, self.cfg.d_model), np.float32)
-            batch["frames"] = jnp.asarray(frames)
-        logits, cache = self._prefill(self.params, batch)
-        cache = api.grow_cache(self.cfg, cache, reserve)
-        self.stats["prefill_calls"] += 1
-        first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        state = GenState(cache=cache, cur=first[:, None], pos=S,
-                         reserve=reserve, padded_b=B)
-        self.stats["tokens_out"] += int(B)
-        return np.asarray(first), state
+        with obs.region("engine.prefill"):
+            batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
+            if self.cfg.is_encdec:
+                if frames is None:
+                    frames = np.zeros((B, S, self.cfg.d_model), np.float32)
+                batch["frames"] = jnp.asarray(frames)
+            logits, cache = self._prefill(self.params, batch)
+            with obs.region("engine.grow_cache"):
+                cache = api.grow_cache(self.cfg, cache, reserve)
+            self.stats["prefill_calls"] += 1
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            state = GenState(cache=cache, cur=first[:, None], pos=S,
+                             reserve=reserve, padded_b=B)
+            with obs.region("engine.readback"):
+                return np.asarray(first), state
 
     def decode_batch(self, state: GenState) -> np.ndarray:
         """One decode step for every row of the group; returns the (B,)
@@ -103,28 +112,30 @@ class Engine:
         if state.pos >= state.reserve:
             raise ValueError(
                 f"decode past reserved cache length {state.reserve}")
-        logits, state.cache = self._decode(
-            self.params, state.cache, state.cur,
-            jnp.asarray(state.pos, jnp.int32))
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        state.cur = nxt[:, None]
-        state.pos += 1
-        self.stats["decode_steps"] += 1
-        self.stats["tokens_out"] += int(state.padded_b)
-        return np.asarray(nxt)
+        with obs.region("engine.decode"):
+            with obs.region("engine.dispatch"):
+                logits, state.cache = self._decode(
+                    self.params, state.cache, state.cur,
+                    jnp.asarray(state.pos, jnp.int32))
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            state.cur = nxt[:, None]
+            state.pos += 1
+            with obs.region("engine.readback"):
+                return np.asarray(nxt)
 
     def gather_rows(self, state: GenState, rows: Sequence[int]) -> GenState:
         """Slot reuse: re-pack the group's state down to ``rows`` (engine
         batch indices, typically the survivors padded to a smaller batch
         bucket).  Decode cost drops to the new batch shape from the next
         step on."""
-        idx = jnp.asarray(list(rows), jnp.int32)
-        # every cache family is stacked over layers: leaves are
-        # (num_layers, batch, ...), so the batch gather is along axis 1
-        cache = jax.tree_util.tree_map(
-            lambda x: jnp.take(x, idx, axis=1), state.cache)
-        return GenState(cache=cache, cur=state.cur[idx], pos=state.pos,
-                        reserve=state.reserve, padded_b=len(rows))
+        with obs.region("engine.gather"):
+            idx = jnp.asarray(list(rows), jnp.int32)
+            # every cache family is stacked over layers: leaves are
+            # (num_layers, batch, ...), so the batch gather is along axis 1
+            cache = jax.tree_util.tree_map(
+                lambda x: jnp.take(x, idx, axis=1), state.cache)
+            return GenState(cache=cache, cur=state.cur[idx], pos=state.pos,
+                            reserve=state.reserve, padded_b=len(rows))
 
     # -- run-to-completion API ----------------------------------------------
 

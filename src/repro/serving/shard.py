@@ -40,6 +40,7 @@ import os
 import signal
 import sys
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence
 
@@ -232,10 +233,21 @@ class ServeLoop:
             timeout = max(deadline - now(), 0.0)
         else:
             timeout = None                  # idle: park until work arrives
-        envs = self.requests.get_batch(self.spec.max_batch,
-                                       timeout=timeout, cancel=self.stop)
+        # park: idle for want of traffic; poll: waiting with work held.
+        # t anchors the jsonl spans' clock to the profiler's
+        t = time.perf_counter_ns()
+        wait = (obs.region("serve.park", t=t) if timeout is None
+                else obs.region("serve.poll", t=t))
+        with wait:
+            envs = self.requests.get_batch(self.spec.max_batch,
+                                           timeout=timeout, cancel=self.stop)
         if not envs:
             return
+        with obs.region("serve.intake"):
+            self._accept(envs)
+
+    def _accept(self, envs: List[Envelope]) -> None:
+        """Decode a drained batch into the batcher and hold its lease."""
         lid = self.requests.detach_lease()
         if any(e.meta.get("stop") for e in envs):
             # a shutdown marker: requeue any real requests that shared
@@ -298,57 +310,70 @@ class ServeLoop:
         if not done:
             return
         t_fin = now()
-        for req, toks in done:
-            if req.meta.get("trace"):
-                obs.span(req.task_id, "decode", active.t_decode0, t_fin,
-                         attempt=req.meta.get("attempt", 0),
-                         new_tokens=len(toks))
-            self._publish(req, list(toks), success=True)
+        with obs.region("serve.publish", n=len(done)):
+            for req, toks in done:
+                if req.meta.get("trace"):
+                    obs.span(req.task_id, "decode", active.t_decode0, t_fin,
+                             attempt=req.meta.get("attempt", 0),
+                             new_tokens=len(toks))
+                self._publish(req, list(toks), success=True)
         g.retire_finished()
         target = g.compaction(active.state.padded_b)
         if target is not None:
             idx = list(g.slots)
             idx += [idx[0]] * (target - len(idx))
-            active.state = self.engine.gather_rows(active.state, idx)
+            with obs.region("serve.compact"):
+                active.state = self.engine.gather_rows(active.state, idx)
             g.reset_slots()
             self.stats["compactions"] += 1
 
     def _admit(self) -> None:
         """Prefill every micro-batch the batcher deems ready."""
         for mb in self.batcher.pop_ready(now()):
-            t_admit = now()
-            obs.observe("batch_occupancy",
-                        len(mb.requests) / self.spec.max_batch)
-            for req in mb.requests:
-                obs.observe("infer_queue_delay", t_admit - req.enqueue_t)
-                if req.meta.get("trace"):
-                    obs.span(req.task_id, "infer_queue", req.enqueue_t,
-                             t_admit, attempt=req.meta.get("attempt", 0),
-                             bucket=mb.bucket)
             padded_b = batch_bucket(len(mb.requests), self.spec.max_batch)
             reserve = mb.bucket + _pow2_at_most(mb.max_new,
                                                 self.spec.max_new_cap)
-            try:
-                first, state = self.engine.prefill_batch(
-                    mb.padded_tokens(padded_b), reserve=reserve)
-            except Exception as exc:        # noqa: BLE001
-                for req in mb.requests:
-                    self._publish_error(req, f"prefill failed: {exc!r}")
+            with obs.region("serve.admit", B=padded_b, N=len(mb.requests),
+                            S=mb.bucket, R=reserve):
+                active = self._prefill(mb, padded_b, reserve)
+            if active is None:
                 continue
-            self.stats["prefills"] += 1
-            obs.counter("prefills").inc()
-            t_prefilled = now()
-            for req in mb.requests:
-                if req.meta.get("trace"):
-                    obs.span(req.task_id, "prefill", t_admit, t_prefilled,
-                             attempt=req.meta.get("attempt", 0),
-                             rows=len(mb.requests))
-            active = _ActiveGroup(DecodeGroup(mb, first, self.spec.max_batch),
-                                  state)
-            active.t_decode0 = t_prefilled
             self._finish_rows(active)       # max_new == 1 rows
             if not active.group.done:
                 self.groups.append(active)
+
+    def _prefill(self, mb: MicroBatch, padded_b: int,
+                 reserve: int) -> Optional[_ActiveGroup]:
+        """One micro-batch's prefill; None where it failed (its requests
+        then got their error results)."""
+        t_admit = now()
+        obs.observe("batch_occupancy",
+                    len(mb.requests) / self.spec.max_batch)
+        for req in mb.requests:
+            obs.observe("infer_queue_delay", t_admit - req.enqueue_t)
+            if req.meta.get("trace"):
+                obs.span(req.task_id, "infer_queue", req.enqueue_t,
+                         t_admit, attempt=req.meta.get("attempt", 0),
+                         bucket=mb.bucket)
+        try:
+            first, state = self.engine.prefill_batch(
+                mb.padded_tokens(padded_b), reserve=reserve)
+        except Exception as exc:            # noqa: BLE001
+            for req in mb.requests:
+                self._publish_error(req, f"prefill failed: {exc!r}")
+            return None
+        self.stats["prefills"] += 1
+        obs.counter("prefills").inc()
+        t_prefilled = now()
+        for req in mb.requests:
+            if req.meta.get("trace"):
+                obs.span(req.task_id, "prefill", t_admit, t_prefilled,
+                         attempt=req.meta.get("attempt", 0),
+                         rows=len(mb.requests))
+        active = _ActiveGroup(DecodeGroup(mb, first, self.spec.max_batch),
+                              state)
+        active.t_decode0 = t_prefilled
+        return active
 
     def _step(self) -> None:
         """One decode step per active group (round-robin), streaming out
@@ -356,17 +381,22 @@ class ServeLoop:
         what interleaves intake/admission with decode."""
         survivors = []
         for active in self.groups:
-            try:
-                nxt = self.engine.decode_batch(active.state)
-            except Exception as exc:        # noqa: BLE001
-                for req in active.group.rows:
-                    self._publish_error(req, f"decode failed: {exc!r}")
-                continue
-            self.stats["decode_steps"] += 1
-            obs.counter("decode_steps").inc()
-            active.group.record_step(nxt)
+            g = active.group
+            # P: the cache position this step writes (the prompt bucket
+            # plus the tokens generated so far, less the first)
+            with obs.region("serve.step", B=active.state.padded_b,
+                            N=len(g.rows), P=g.bucket + g.steps - 1):
+                try:
+                    nxt = self.engine.decode_batch(active.state)
+                except Exception as exc:    # noqa: BLE001
+                    for req in g.rows:
+                        self._publish_error(req, f"decode failed: {exc!r}")
+                    continue
+                self.stats["decode_steps"] += 1
+                obs.counter("decode_steps").inc()
+                g.record_step(nxt)
             self._finish_rows(active)
-            if not active.group.done:
+            if not g.done:
                 survivors.append(active)
         self.groups = survivors
 

@@ -416,6 +416,25 @@ def test_span_name_registry_flags_dynamic_name(tmp_path):
     assert "non-literal" in findings[0].message
 
 
+def test_span_name_registry_checks_region_names(tmp_path):
+    findings = _lint(tmp_path, """\
+        from repro import observability as obs
+
+        def f(which):
+            with obs.region("serve.step", B=1, N=1, P=8):
+                pass
+            with obs.region("serve.stpe"):
+                pass
+            with obs.region("serve." + which):
+                pass
+    """)
+    assert len(findings) == 2
+    assert all(f.pass_name == "span-name-registry" for f in findings)
+    assert "serve.stpe" in findings[0].message
+    assert "REGION_NAMES" in findings[0].message
+    assert "non-literal" in findings[1].message
+
+
 def test_span_name_registry_ignores_other_receivers(tmp_path):
     # Timer.span and arbitrary .counter attributes are not obs calls
     findings = _lint(tmp_path, """\
