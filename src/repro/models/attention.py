@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from repro.distributed import axisenv
@@ -85,11 +86,14 @@ def mha_reference(
 ) -> jax.Array:
     """Blockwise attention; returns (B, Sq, H, hd) in q.dtype.
 
-    GQA is handled by *repeating* K/V to the full head count instead of
-    reshaping Q to (KVH, G, hd): the repeat keeps the head axis intact, so
-    a "model"-sharded head dimension propagates through every einsum with
-    no resharding (the (KVH, G) reshape misaligns GSPMD shard boundaries
-    whenever KVH < the mesh axis).  The repeat is a chunk-local transient.
+    GQA in the blockwise path *repeats* K/V to the full head count instead
+    of reshaping Q to (KVH, G, hd): the repeat keeps the head axis intact,
+    so a "model"-sharded head dimension propagates through every einsum
+    with no resharding (the (KVH, G) reshape misaligns GSPMD shard
+    boundaries whenever KVH < the mesh axis).  The repeat is a chunk-local
+    transient.  The decode path (Sq <= 8) reads the whole cache per call,
+    so there it views q as (KVH, G) and reads K/V as stored, unless a mesh
+    axis shards the heads (see `_decode_attention`).
     """
     B, Sq, H, hd = q.shape
     _, Sk, KVH, _ = k.shape
@@ -103,20 +107,10 @@ def mha_reference(
     static_q0 = 0 if dyn_offset else q_offset
 
     qf = q.astype(jnp.float32) * scale
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
     kpos_all = jnp.arange(Sk)
-
-    def kv_chunk(t, k0, k1):
-        c = t[:, k0:k1]
-        return jnp.repeat(c, G, axis=2) if G > 1 else c   # (B,ck,H,hd)
 
     # decode fast path: tiny Sq, single pass over the whole cache
     if Sq <= 8:
-        ke, ve = kv_chunk(kf, 0, Sk), kv_chunk(vf, 0, Sk)
-        s = jnp.einsum("bihd,bjhd->bhij", qf, ke)         # (B,H,Sq,Sk)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
         qpos = jnp.arange(Sq) + q_offset
         mask = jnp.ones((Sq, Sk), bool)
         if causal:
@@ -125,10 +119,15 @@ def mha_reference(
             mask &= qpos[:, None] - kpos_all[None, :] < window
         if valid_len is not None:
             mask &= kpos_all[None, :] < valid_len
-        s = jnp.where(mask, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhij,bjhd->bihd", p, ve)
+        o = _decode_attention(qf, k, v, mask, softcap)
         return o.astype(q.dtype)
+
+    kf = k.astype(jnp.float32)
+    vf = v.astype(jnp.float32)
+
+    def kv_chunk(t, k0, k1):
+        c = t[:, k0:k1]
+        return jnp.repeat(c, G, axis=2) if G > 1 else c   # (B,ck,H,hd)
 
     cq = min(chunk_q, Sq)
     ck = min(chunk_k, Sk)
@@ -172,6 +171,35 @@ def mha_reference(
         out_chunks.append(acc / jnp.maximum(l_t, 1e-30)[..., None])
     o = jnp.concatenate(out_chunks, axis=1) if n_q > 1 else out_chunks[0]
     return o.astype(q.dtype)                              # (B,Sq,H,hd)
+
+
+def _decode_attention(qf, k, v, mask, softcap):
+    """One pass of f32 attention over the whole cache for a few queries.
+
+    qf (B, Sq, H, hd) f32, pre-scaled; k/v (B, Sk, KVH, hd) in the cache's
+    dtype; mask (Sq, Sk).  Scores, softmax and the P.V sum are f32; K/V are
+    upcast inside the reductions, which is exact.  With the heads
+    unsharded, GQA views q as (B, Sq, KVH, G, hd) against K/V as stored:
+    each layer's cache is read once, with no f32 or group-repeated copy.
+    Where a mesh axis shards the heads, K/V are repeated to H heads, which
+    keeps the head axis whole for GSPMD (see `mha_reference`).
+    """
+    B, Sq, H, hd = qf.shape
+    G = H // k.shape[2]
+    if axisenv.resolve("model", H) is None:
+        qf = qf.reshape(B, Sq, k.shape[2], G, hd)
+        eq_s, eq_o = "bikgd,bjkd->bkgij", "bkgij,bjkd->bikgd"
+    else:
+        if G > 1:
+            k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+        eq_s, eq_o = "bihd,bjhd->bhij", "bhij,bjhd->bihd"
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum(eq_s, qf, k.astype(jnp.float32), precision=hi)
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    p = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1)
+    o = jnp.einsum(eq_o, p, v.astype(jnp.float32), precision=hi)
+    return o.reshape(B, Sq, H, hd)
 
 
 def attend(q, k, v, *, cfg: ModelConfig, causal=True, window=None,
@@ -244,10 +272,12 @@ def output_proj(params, o, cfg: ModelConfig):
 
 
 def self_attention(params, x, cfg: ModelConfig, *, cos, sin, causal=True,
-                   window=None, cache=None, cur_len=None):
+                   window=None, cache=None, cur_len=None, layer=None):
     """One self-attention application.
 
-    cache: None (full-sequence) or dict {k, v} of (B, S_max, KVH, hd) arrays.
+    cache: None (full-sequence) or dict {k, v} of (B, S_max, KVH, hd) arrays;
+    with `layer` (a scalar array), the layer-stacked (L, B, S_max, KVH, hd)
+    cache, of which only layer `layer` is written and read.
     cur_len: scalar array; when cache is given, the new tokens are written at
     [cur_len, cur_len + Sq) and attention sees positions < cur_len + Sq.
     Returns (out (B,Sq,D), new_cache).
@@ -257,15 +287,37 @@ def self_attention(params, x, cfg: ModelConfig, *, cos, sin, causal=True,
         o = attend(q, k_new, v_new, cfg=cfg, causal=causal, window=window)
         return output_proj(params, o, cfg), None
     # decode / incremental path
-    B = x.shape[0]
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, _as_idx(cur_len), 0, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, _as_idx(cur_len), 0, 0))
-    k = axisenv.constrain(k, "batch", None, "kv", None)
-    v = axisenv.constrain(v, "batch", None, "kv", None)
+    def heads(t):
+        return axisenv.constrain(t, "batch", None, "kv", None)
+
+    at = (0, _as_idx(cur_len), 0, 0)
+    if layer is None:
+        k = heads(jax.lax.dynamic_update_slice(cache["k"], k_new, at))
+        v = heads(jax.lax.dynamic_update_slice(cache["v"], v_new, at))
+        cache = {"k": k, "v": v}
+    else:
+        # only the new positions are written, into the stacked cache in
+        # place; attention reads the layer's slice of the stack
+        at = (_as_idx(layer),) + at
+        cache = {n: _stacked_in_place(
+                     jax.lax.dynamic_update_slice(cache[n], t[None], at))
+                 for n, t in (("k", k_new), ("v", v_new))}
+        k, v = (heads(jax.lax.dynamic_index_in_dim(cache[n], at[0],
+                                                   keepdims=False))
+                for n in ("k", "v"))
     valid = cur_len + x.shape[1]
     o = attend(q, k, v, cfg=cfg, causal=True, window=window,
                q_offset=cur_len, valid_len=valid)
-    return output_proj(params, o, cfg), {"k": k, "v": v}
+    return output_proj(params, o, cfg), cache
+
+
+def _stacked_in_place(t):
+    """A layer-stacked cache inside the decode loop, pinned to the sharding
+    and the row-major layout it has outside: left free, XLA lays the loop's
+    copy out for the attention reads, so every step would relayout the
+    whole cache on entry and back on exit."""
+    t = axisenv.constrain(t, None, "batch", None, "kv", None)
+    return with_layout_constraint(t, Layout(tuple(range(t.ndim))))
 
 
 def cross_attention(params, x, enc_kv, cfg: ModelConfig):

@@ -11,7 +11,8 @@ One parameter tree + one apply path per family, all scanned over layers with
 
 The same block functions serve train (no cache), prefill (collect cache) and
 decode (consume + update cache); caches are stacked over layers so they flow
-through the scans as xs/ys.
+through the scans as xs/ys, except the dense/MoE decode KV cache, which rides
+in the scan's loop state and is updated in place (`_scan_kv_in_place`).
 """
 from __future__ import annotations
 
@@ -90,7 +91,7 @@ def _residual(h, cfg):
 
 def apply_dense_block(p, h, cfg: ModelConfig, *, cos, sin, window=None,
                       causal=True, cache=None, cur_len=None, enc_kv=None,
-                      collect_cache=False):
+                      collect_cache=False, layer=None):
     a_in = rmsnorm(p["ln1"], h, cfg.norm_eps)
     if collect_cache:
         q, k, v = attn.project_qkv(p["attn"], a_in, cfg, cos, sin)
@@ -100,7 +101,7 @@ def apply_dense_block(p, h, cfg: ModelConfig, *, cos, sin, window=None,
     else:
         a_out, new_cache = attn.self_attention(
             p["attn"], a_in, cfg, cos=cos, sin=sin, causal=causal,
-            window=window, cache=cache, cur_len=cur_len)
+            window=window, cache=cache, cur_len=cur_len, layer=layer)
     h = _residual(h + _maybe_post(p, "ln1_post", a_out, cfg), cfg)
 
     if enc_kv is not None:
@@ -199,6 +200,25 @@ def _scan_uniform(params, h, cfg, apply_fn, cache, collect):
     return h, new_cache, aux
 
 
+def _scan_kv_in_place(params, h, cfg, apply_fn, cache):
+    """Decode over an (L, ...)-stacked block group whose stacked KV cache is
+    carried in the loop state: layer i writes only its new positions and
+    reads its slice in place, where xs/ys would copy each layer's whole K/V
+    out of the stack and write it back every step."""
+    def body(carry, xs):
+        h, aux, c = carry
+        p, i = xs
+        h, c, a = apply_fn(p, h, c, i)
+        return (h, aux + a, c), None
+
+    L = jax.tree.leaves(params)[0].shape[0]
+    (h, aux, cache), _ = jax.lax.scan(
+        body, (h, jnp.zeros((), jnp.float32), cache),
+        (params, jnp.arange(L, dtype=jnp.int32)), length=L,
+        unroll=cfg.scan_unroll)
+    return h, cache, aux
+
+
 def run_stack(params, h, cfg: ModelConfig, *, cos, sin, cache=None,
               cur_len=None, collect_cache=False):
     """Run the decoder stack.  Returns (h, new_cache, aux_loss).
@@ -221,6 +241,12 @@ def run_stack(params, h, cfg: ModelConfig, *, cos, sin, cache=None,
         return _run_local_global_stack(params, h, cfg, cos=cos, sin=sin,
                                        cache=cache, cur_len=cur_len,
                                        collect_cache=collect_cache)
+
+    if cache is not None:
+        def app_decode(p, x, c, i):
+            return apply_dense_block(p, x, cfg, cos=cos, sin=sin, cache=c,
+                                     cur_len=cur_len, layer=i)
+        return _scan_kv_in_place(params["uniform"], h, cfg, app_decode, cache)
 
     def app(p, x, c):
         return apply_dense_block(p, x, cfg, cos=cos, sin=sin, cache=c,

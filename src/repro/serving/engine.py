@@ -28,7 +28,6 @@ steady-state tokens/sec over warm executables only.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
 from typing import Optional, Sequence
 
 import jax
@@ -40,15 +39,41 @@ from repro.configs.base import ModelConfig
 from repro.models import api
 
 
-@dataclass
 class GenState:
-    """One decode group's device state between steps."""
+    """One decode group's device state between steps.
 
-    cache: object                 # pytree; every leaf leads with batch
-    cur: jnp.ndarray              # (B, 1) last emitted token per row
-    pos: int                      # tokens already written to the cache
-    reserve: int                  # cache capacity (prompt + generation)
-    padded_b: int                 # current batch dimension
+    Each decode step is given the cache to reuse in place (donated): the
+    arrays it read are deleted.  So a cache handed out through ``cache``,
+    read or set by a caller who may still hold it, is copied before the
+    next step donates it; the engine's own steps pass theirs on uncopied.
+    """
+
+    def __init__(self, cache, cur, pos: int, reserve: int, padded_b: int):
+        self._cache = cache       # pytree; every leaf leads with batch
+        self._shared = False      # a caller may hold ``_cache``
+        self.cur = cur            # (B, 1) last emitted token per row
+        self.pos = pos            # tokens already written to the cache
+        self.reserve = reserve    # cache capacity (prompt + generation)
+        self.padded_b = padded_b  # current batch dimension
+
+    @property
+    def cache(self):
+        self._shared = True
+        return self._cache
+
+    @cache.setter
+    def cache(self, cache):
+        self._cache, self._shared = cache, True
+
+    def take_cache(self):
+        """The cache for a step that consumes it: a copy if shared."""
+        if self._shared:
+            return jax.tree.map(jnp.copy, self._cache)
+        return self._cache
+
+    def put_cache(self, cache):
+        """Hold a step's new cache, which no caller has seen."""
+        self._cache, self._shared = cache, False
 
 
 class Engine:
@@ -66,7 +91,8 @@ class Engine:
             return api.decode_step(params, cfg, cache, cur, pos)
 
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(decode_step)
+        # the cache is donated: a step writes its new positions in place
+        self._decode = jax.jit(decode_step, donate_argnums=1)
         self._warm: set = set()   # (B, S, max_new) shapes already compiled
         self.stats = {"prefill_calls": 0, "wall": 0.0, "compile_wall": 0.0,
                       "warm_tokens": 0}
@@ -114,9 +140,10 @@ class Engine:
                 f"decode past reserved cache length {state.reserve}")
         with obs.region("engine.decode"):
             with obs.region("engine.dispatch"):
-                logits, state.cache = self._decode(
-                    self.params, state.cache, state.cur,
+                logits, cache = self._decode(
+                    self.params, state.take_cache(), state.cur,
                     jnp.asarray(state.pos, jnp.int32))
+                state.put_cache(cache)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             state.cur = nxt[:, None]
             state.pos += 1
@@ -133,7 +160,7 @@ class Engine:
             # every cache family is stacked over layers: leaves are
             # (num_layers, batch, ...), so the batch gather is along axis 1
             cache = jax.tree_util.tree_map(
-                lambda x: jnp.take(x, idx, axis=1), state.cache)
+                lambda x: jnp.take(x, idx, axis=1), state._cache)
             return GenState(cache=cache, cur=state.cur[idx], pos=state.pos,
                             reserve=state.reserve, padded_b=len(rows))
 

@@ -59,8 +59,13 @@ def test_engine_ssm_arch():
 def test_engine_stepwise_matches_generate():
     """The shard's stepwise prefill/decode path emits exactly the tokens
     ``generate`` would, and ``gather_rows`` keeps the surviving rows'
-    continuations identical after a slot-reuse compaction."""
-    cfg = get_config("internlm2-1.8b", reduced=True)
+    continuations identical after a slot-reuse compaction.  Each step
+    consumes the cache it is given (donated), the compaction's gather
+    too, on a grouped-query config (8 query heads over 2 KV heads); the
+    tokens are also those of repeated full forward passes."""
+    import jax.numpy as jnp
+    cfg = get_config("internlm2-1.8b", reduced=True).replace(
+        num_heads=8, num_kv_heads=2)
     params = api.init_params(cfg, jax.random.PRNGKey(0))
     eng = Engine(cfg, params, max_new=6)
     prompts = np.random.default_rng(2).integers(
@@ -78,3 +83,10 @@ def test_engine_stepwise_matches_generate():
     np.testing.assert_array_equal(full, ref[:, 16:16 + 3])
     np.testing.assert_array_equal(np.stack(tail, axis=1),
                                   ref[[0, 2], 16 + 3:])
+
+    toks = jnp.asarray(prompts)
+    for _ in range(6):
+        logits, _ = api.forward(params, cfg, {"tokens": toks})
+        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        toks = jnp.concatenate([toks, nxt[:, None]], axis=1)
+    np.testing.assert_array_equal(ref, np.asarray(toks))

@@ -9,7 +9,9 @@ reason.  Nothing runs, so they say nothing about results or times; they
 catch what the chip's compiler refuses (kernel layouts interpret mode
 accepts, programs that do not fit the device).
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,3 +97,62 @@ def test_internlm2_full_width_decode_step_fits_one_chip(one_chip):
     decode = jax.jit(lambda p, c, t, n: api.decode_step(p, cfg, c, t, n))
     compiled = decode.lower(params, cache, tok, cur).compile()
     assert _memory_bytes(compiled) < V5E_HBM_BYTES
+
+
+_NO_WRITE = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+             "conditional", "call"}
+
+
+def _writes(hlo: str) -> list:
+    """(opcode, dtype, element count, name of the called computation) of
+    every array an optimized module writes to memory: the outputs of the
+    instructions outside fusion bodies."""
+    out, comp, fused = [], None, False
+    for line in hlo.splitlines():
+        if not line.startswith(" "):
+            comp = line.split(" ")[0] if line.strip() else None
+            fused = comp is not None and "fused_computation" in comp
+            continue
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w\-]+)\(", line)
+        if fused or not m or m.group(2) in _NO_WRITE:
+            continue
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", m.group(1)):
+            n = math.prod(int(d) for d in dims.split(",") if d)
+            out.append((m.group(2), dtype, n, calls and calls.group(1)))
+    return out
+
+
+def _root_op(hlo: str, computation: str) -> str:
+    body = hlo[hlo.index(f"\n%{computation} "):]
+    root = re.search(r"ROOT %\S+ = .*? ([\w\-]+)\(", body)
+    return root.group(1)
+
+
+def test_qwen3_decode_step_reads_the_kv_cache_in_place(one_chip):
+    """The served decode step at qwen3-8b's widths and the served cache
+    geometry (B=32, reserve 192), compiled for the chip: the cache is
+    aliased to the step's output, and per layer no op writes an f32 or
+    group-repeated copy of the layer's K/V, and the only ops that write
+    the layer-stacked cache are in-place updates of the new position."""
+    cfg = get_config("qwen3-8b").replace(num_layers=2)
+    B, R = 32, 192
+    KVH, hd, H = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.num_heads
+    params = _shapes(api.abstract_params(cfg), one_chip)
+    cache = _shapes(jax.eval_shape(lambda: api.init_cache(cfg, B, R)),
+                    one_chip)
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    cur = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    decode = jax.jit(lambda p, c, t, n: api.decode_step(p, cfg, c, t, n),
+                     donate_argnums=1)
+    hlo = decode.lower(params, cache, tok, cur).compile().as_text()
+    assert "input_output_alias" in hlo
+    layer, stack = B * R * KVH * hd, cfg.num_layers * B * R * KVH * hd
+    writes = _writes(hlo)
+    assert not [w for w in writes if w[1] == "f32" and w[2] == layer]
+    assert not [w for w in writes if w[2] == B * R * H * hd]
+    stacked = [w for w in writes if w[2] == stack]
+    assert len(stacked) == 2, stacked
+    assert all(w[0] == "fusion"
+               and _root_op(hlo, w[3]) == "dynamic-update-slice"
+               for w in stacked), stacked
