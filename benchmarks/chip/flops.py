@@ -1,5 +1,6 @@
 """Operations and bytes of one engine call, computed from its shapes and
-the configuration file (not from the program).
+the configuration file (not from the program), by the configuration's
+family (``families``).
 
 A prefill call runs ``B`` prompts of ``S`` tokens through every layer
 and the head at the last position.  A decode call runs ``B`` rows one
@@ -13,68 +14,29 @@ requests in it (MFU).
 """
 from __future__ import annotations
 
-from benchmarks.chip.weights import dims, layer_leaves
-
-BYTES = 2                                   # bf16 weights and KV cache
-
-
-def _numel(shape) -> int:
-    n = 1
-    for s in shape:
-        n *= s
-    return n
-
-
-def _layer_matmul_params(c: dict) -> int:
-    return sum(_numel(shape) for shape, fan_in in layer_leaves(c).values()
-               if fan_in is not None)
-
-
-def _layer_params(c: dict) -> int:
-    return sum(_numel(shape) for shape, _ in layer_leaves(c).values())
-
-
-def _head_params(c: dict) -> int:
-    """The final norm and the unembedding (the table itself when tied)."""
-    z = dims(c)
-    return z["d"] + z["d"] * z["V"]
+from benchmarks.chip import families
 
 
 def prefill_flops(c: dict, B: int, S: int) -> float:
-    z = dims(c)
-    linear = 2.0 * B * S * z["L"] * _layer_matmul_params(c)
-    attn = 4.0 * B * z["L"] * z["H"] * z["hd"] * S * (S + 1) / 2
-    head = 2.0 * B * z["d"] * z["V"]
-    return linear + attn + head
+    return families.of(c).prefill_flops(c, B, S)
 
 
 def decode_flops(c: dict, B: int, pos: int) -> float:
-    z = dims(c)
-    linear = 2.0 * B * z["L"] * _layer_matmul_params(c)
-    attn = 4.0 * B * z["L"] * z["H"] * z["hd"] * (pos + 1)
-    head = 2.0 * B * z["d"] * z["V"]
-    return linear + attn + head
-
-
-def _kv_bytes(c: dict, rows: int, positions: int) -> float:
-    z = dims(c)
-    return 2.0 * z["L"] * rows * positions * z["KV"] * z["hd"] * BYTES
+    return families.of(c).decode_flops(c, B, pos)
 
 
 def weight_bytes(c: dict, rows: int) -> float:
     """Weights one call reads: all but the embedding table, plus the
     table's ``rows`` rows."""
-    z = dims(c)
-    return (z["L"] * _layer_params(c) + _head_params(c)
-            + rows * z["d"]) * BYTES
+    return families.of(c).weight_bytes(c, rows)
 
 
 def prefill_bytes(c: dict, B: int, S: int) -> float:
-    return weight_bytes(c, B * S) + _kv_bytes(c, B, S)
+    return families.of(c).prefill_bytes(c, B, S)
 
 
 def decode_bytes(c: dict, B: int, pos: int) -> float:
-    return weight_bytes(c, B) + _kv_bytes(c, B, pos + 1)
+    return families.of(c).decode_bytes(c, B, pos)
 
 
 def least_seconds(flops: float, nbytes: float, peak: dict) -> tuple:

@@ -1,14 +1,13 @@
-"""Plain float32 reference of the dense decoders the benchmark serves
-(InternLM2, Qwen3 with its per-head q/k RMS norm).
+"""Plain float32 reference of the models the benchmark serves.
 
 Written from the architectures, not from the program: it imports
-nothing of ``repro`` and takes nothing the program made.  Its weights
-come from ``weights`` and the seed, one layer at a time (a whole
-Qwen3-8B stage in float32 would not fit a 16 GB chip), made in the
-dtype they are served in and cast to float32, with every matrix product at
-``jax.default_matmul_precision("highest")``.  It runs the full forward
-pass over each prompt followed by the tokens the server generated, with
-no cache, padding or batching of the program's kind.
+nothing of ``repro`` and takes nothing the program made.  Each
+configuration's family (``families``) runs the forward pass over each
+prompt followed by the tokens the server generated, with no cache,
+padding or batching of the program's kind, its weights from ``weights``
+and the seed, made in the dtype they are served in and cast to float32,
+with every matrix product at ``jax.default_matmul_precision("highest")``.
+This module holds what the families share, and what reads their logits.
 
 ``quant="fp8"`` is the control: the same forward with the input of
 every linear layer and the weight rounded to float8 e4m3 (scaled per
@@ -20,13 +19,11 @@ program on the chip to separate them; PERF.md.)
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip import weights as W
+from benchmarks.chip import families
 
 F32 = jnp.float32
 
@@ -57,87 +54,12 @@ def _rope(t, cos, sin):
     return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("z", "quant"))
-def _layer(x, w, *, z, quant):
-    """One decoder layer over (N, T, d) float32."""
-    N, T, d = x.shape
-    H, KV, hd, eps = z["H"], z["KV"], z["hd"], z["eps"]
-    w = {k: v.astype(F32) for k, v in w.items()}
-    inv = 1.0 / z["theta"] ** (jnp.arange(0, hd, 2, dtype=F32) / hd)
-    ang = jnp.arange(T, dtype=F32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-
-    a = _norm(x, w["ln1/scale"], eps)
-    q = _mm(a, w["attn/wq"].reshape(d, H * hd), quant).reshape(N, T, H, hd)
-    k = _mm(a, w["attn/wk"].reshape(d, KV * hd), quant).reshape(N, T, KV, hd)
-    v = _mm(a, w["attn/wv"].reshape(d, KV * hd), quant).reshape(N, T, KV, hd)
-    if z["qk_norm"]:
-        q = _norm(q, w["attn/q_norm"], eps)
-        k = _norm(k, w["attn/k_norm"], eps)
-    q, k = _rope(q, cos, sin), _rope(k, cos, sin)
-    # query head h reads kv head h // (H // KV)
-    k, v = jnp.repeat(k, H // KV, axis=2), jnp.repeat(v, H // KV, axis=2)
-    s = jnp.einsum("nqhk,nshk->nhqs", q, k) / np.sqrt(hd)
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-    o = jnp.einsum("nhqs,nshk->nqhk", p, v).reshape(N, T, H * hd)
-    x = x + _mm(o, w["attn/wo"].reshape(H * hd, d), quant)
-
-    m = _norm(x, w["ln2/scale"], eps)
-    act = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[z["act"]]
-    g = act(_mm(m, w["ffn/wi_gate"], quant)) * _mm(m, w["ffn/wi_up"], quant)
-    return x + _mm(g, w["ffn/wo"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("c_items",))
-def _layer_weights(key, layer, *, c_items):
-    c = dict(c_items)
-    return W.one_layer(key, c, layer, jnp.dtype(c["dtype"]))
-
-
-@functools.partial(jax.jit, static_argnames=("c_items", "path"))
-def _global(key, *, c_items, path):
-    c = dict(c_items)
-    return W.global_leaf(key, c, path, jnp.dtype(c["dtype"]))
-
-
-def _hashable(c: dict) -> tuple:
-    return tuple(sorted((k, v) for k, v in c.items()
-                        if isinstance(v, (int, float, str, bool))))
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "quant"))
-def _head(h, scale, unembed, *, eps, quant):
-    h = _norm(h, scale.astype(F32), eps)
-    return _mm(h, unembed.astype(F32), quant)
-
-
 def logits(c: dict, seed: int, tokens: np.ndarray, positions: np.ndarray,
            quant=None) -> np.ndarray:
     """Float32 logits (N, P, V) of the next token after each of
-    ``positions`` (N, P) of the rows of ``tokens`` (N, T)."""
-    z = W.dims(c)
-    ci = _hashable(c)
-    key = W.seed_key(seed)
-    zs = tuple(sorted(z.items()))
-    with jax.default_matmul_precision("highest"):
-        emb = _global(key, c_items=ci, path="tok/embed")
-        x = jnp.take(emb, jnp.asarray(tokens), axis=0).astype(F32)
-        del emb
-        for layer in range(z["L"]):
-            w = _layer_weights(key, jnp.int32(layer), c_items=ci)
-            x = _layer(x, w, z=_Static(zs), quant=quant)
-        idx = jnp.asarray(positions)
-        h = jnp.take_along_axis(x, idx[..., None], axis=1)      # (N, P, d)
-        del x
-        unembed_path = ("tok/embed" if c.get("tie_word_embeddings")
-                        else "tok/unembed")
-        un = _global(key, c_items=ci, path=unembed_path)
-        if c.get("tie_word_embeddings"):
-            un = un.T
-        out = _head(h, _global(key, c_items=ci, path="final_norm/scale"),
-                    un, eps=float(z["eps"]), quant=quant)
-        return np.asarray(out)
+    ``positions`` (N, P) of the rows of ``tokens`` (N, T): the
+    configuration's family's reference."""
+    return families.of(c).logits(c, seed, tokens, positions, quant)
 
 
 def greedy(c: dict, seed: int, prompts: list, counts: list,
@@ -161,20 +83,6 @@ def greedy(c: dict, seed: int, prompts: list, counts: list,
                 if len(p) + j < T:
                     tokens[i, len(p) + j] = pick[i]
     return out
-
-
-class _Static(dict):
-    """The sizes as a hashable static argument that reads like a dict."""
-
-    def __init__(self, items):
-        super().__init__(items)
-        self._items = tuple(items)
-
-    def __hash__(self):
-        return hash(self._items)
-
-    def __eq__(self, other):
-        return isinstance(other, _Static) and self._items == other._items
 
 
 def served_gaps(c: dict, seed: int, prompts: list, served: list) -> dict:

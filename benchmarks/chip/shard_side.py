@@ -26,6 +26,7 @@ import threading
 import time
 from pathlib import Path
 
+from benchmarks.chip import families
 from benchmarks.chip import weights as W
 
 ANNOTATE = "bench."
@@ -46,16 +47,8 @@ def wait_file(path: Path, poll: float = 0.01):
 
 def program_config(c: dict):
     """The program's config for the configuration file's architecture,
-    with every size as the file states it."""
-    from repro.configs.base import get_config
-    z = W.dims(c)
-    return get_config(c["program_arch"]).replace(
-        num_layers=z["L"], d_model=z["d"], num_heads=z["H"],
-        num_kv_heads=z["KV"], head_dim=z["hd"], d_ff=z["f"],
-        vocab_size=z["V"], norm_eps=z["eps"], rope_theta=z["theta"],
-        qk_norm=z["qk_norm"], act=z["act"],
-        tie_embeddings=bool(c.get("tie_word_embeddings")),
-        param_dtype=c["dtype"], compute_dtype=c["dtype"])
+    with every size as the file states it: the family's."""
+    return families.of(c).program_config(c)
 
 
 def make_params(cfg, c: dict, seed: int):
@@ -65,9 +58,8 @@ def make_params(cfg, c: dict, seed: int):
     import jax
     import jax.numpy as jnp
     from repro.models import api
-    want = {p: s for p, (s, _) in W.global_leaves(c).items()}
-    L = W.dims(c)["L"]
-    want.update({p: (L,) + s for p, (s, _) in W.layer_leaves(c).items()})
+    want = {p: ((layers,) if layers else ()) + s
+            for p, (s, _, layers) in W.leaves(c).items()}
     have = {p: tuple(a.shape)
             for p, a in W.flatten(api.abstract_params(cfg)).items()}
     if have != want:

@@ -6,7 +6,9 @@ of the leaf's path and, for a layer's leaf, with the layer index.  The
 served model makes every leaf in one jitted call; the reference makes
 one layer at a time with the same keys and gets the same values, so it
 never needs the program's arrays.  Norm scales are drawn around 1 (not
-set to 1), so a norm whose scale is dropped changes the answer.
+set to 1), so a norm whose scale is dropped changes the answer.  Which
+leaves there are, their shapes and fan-ins, is the configuration's
+family's (``families``, ``leaves``).
 """
 from __future__ import annotations
 
@@ -16,46 +18,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks.chip import families
+
 HALF = 1 << 15                  # integer grid of the uniform draws
 
 
-def dims(c: dict) -> dict:
-    """The sizes of a configuration file, under short names."""
-    return {"d": c["hidden_size"], "L": c["num_hidden_layers"],
-            "H": c["num_attention_heads"], "KV": c["num_key_value_heads"],
-            "hd": c["head_dim"], "f": c["intermediate_size"],
-            "V": c["vocab_size"], "eps": c["rms_norm_eps"],
-            "theta": c["rope_theta"], "qk_norm": bool(c.get("qk_norm")),
-            "act": c.get("hidden_act", "silu")}
+def leaves(c: dict) -> dict:
+    """path -> (shape of one layer's leaf, fan-in, layers): the family's."""
+    return families.of(c).leaves(c)
 
 
-def layer_leaves(c: dict) -> dict:
-    """path -> (shape of one layer's leaf, fan-in or None for a norm)."""
-    z = dims(c)
-    d, H, KV, hd, f = z["d"], z["H"], z["KV"], z["hd"], z["f"]
-    out = {"stack/uniform/ln1/scale": ((d,), None),
-           "stack/uniform/attn/wq": ((d, H, hd), d),
-           "stack/uniform/attn/wk": ((d, KV, hd), d),
-           "stack/uniform/attn/wv": ((d, KV, hd), d),
-           "stack/uniform/attn/wo": ((H, hd, d), H * hd),
-           "stack/uniform/ln2/scale": ((d,), None),
-           "stack/uniform/ffn/wi_gate": ((d, f), d),
-           "stack/uniform/ffn/wi_up": ((d, f), d),
-           "stack/uniform/ffn/wo": ((f, d), f)}
-    if z["qk_norm"]:
-        out["stack/uniform/attn/q_norm"] = ((hd,), None)
-        out["stack/uniform/attn/k_norm"] = ((hd,), None)
-    return out
-
-
-def global_leaves(c: dict) -> dict:
-    """path -> (shape, fan-in; 1 for the embedding table, None for a norm)."""
-    z = dims(c)
-    out = {"tok/embed": ((z["V"], z["d"]), 1),
-           "final_norm/scale": ((z["d"],), None)}
-    if not c.get("tie_word_embeddings"):
-        out["tok/unembed"] = ((z["d"], z["V"]), z["d"])
-    return out
+def _group(path: str) -> str:
+    """The group of a stacked leaf: its path's first two parts."""
+    return "/".join(path.split("/")[:2])
 
 
 def seed_key(seed: int):
@@ -80,34 +55,44 @@ def _leaf_key(key, path: str):
     return jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
 
 
+def _layer_key(key, path: str, layer):
+    return jax.random.fold_in(_leaf_key(key, path), layer)
+
+
 def global_leaf(key, c: dict, path: str, dtype=jnp.bfloat16):
-    shape, fan_in = global_leaves(c)[path]
+    shape, fan_in, _ = leaves(c)[path]
     return _draw(_leaf_key(key, path), shape, fan_in, dtype)
 
 
 def layer_leaf(key, c: dict, path: str, layer, dtype=jnp.bfloat16):
-    shape, fan_in = layer_leaves(c)[path]
-    return _draw(jax.random.fold_in(_leaf_key(key, path), layer), shape,
-                 fan_in, dtype)
+    shape, fan_in, _ = leaves(c)[path]
+    return _draw(_layer_key(key, path, layer), shape, fan_in, dtype)
 
 
 def all_leaves(key, c: dict, dtype=jnp.bfloat16) -> dict:
-    """path -> array for the whole model; layer leaves stacked over
-    layers.  Meant to run inside one ``jax.jit``."""
-    L = dims(c)["L"]
-    out = {p: global_leaf(key, c, p, dtype) for p in global_leaves(c)}
-    for p in layer_leaves(c):
-        out[p] = jax.vmap(lambda l, p=p: layer_leaf(key, c, p, l, dtype))(
-            jnp.arange(L))
+    """path -> array for the whole model; a stacked leaf stacked over
+    its group's layers.  Meant to run inside one ``jax.jit``."""
+    out = {}
+    for p, (_, _, layers) in leaves(c).items():
+        if layers:
+            out[p] = jax.vmap(lambda l, p=p: layer_leaf(key, c, p, l, dtype))(
+                jnp.arange(layers))
+        else:
+            out[p] = global_leaf(key, c, p, dtype)
     return out
 
 
-def one_layer(key, c: dict, layer, dtype=jnp.bfloat16) -> dict:
-    """Path below the stack (``attn/wq``, ``ln1/scale``, ...) -> one
-    layer's leaf."""
-    return {p.removeprefix("stack/uniform/"): layer_leaf(key, c, p, layer,
-                                                          dtype)
-            for p in layer_leaves(c)}
+def one_layer(key, c: dict, layer, dtype=jnp.bfloat16, group=None) -> dict:
+    """Path below the group (``attn/wq``, ``ln1/scale``, ...) -> one
+    layer's leaf of ``group``; without ``group``, of the only one."""
+    stacked = [p for p, (_, _, layers) in leaves(c).items() if layers]
+    if group is None:
+        groups = {_group(p) for p in stacked}
+        if len(groups) != 1:
+            raise ValueError(f"name one of the groups {sorted(groups)}")
+        group, = groups
+    return {p.removeprefix(group + "/"): layer_leaf(key, c, p, layer, dtype)
+            for p in stacked if _group(p) == group}
 
 
 def as_tree(flat: dict) -> dict:

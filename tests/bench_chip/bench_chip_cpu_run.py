@@ -2,7 +2,10 @@
 with a fault planted under the timed path.  Prints the result line.
 
     python tests/bench_chip/bench_chip_cpu_run.py [--fault NAME] \
-        [--trace 1] [--control fp8]
+        [--trace 1] [--control fp8] [--config kimi-k2-reduced]
+
+``--config`` serves a test-only configuration of ``extra_families/``
+(whose family module is found there) in place of the reduced InternLM2.
 
 The run forks its shard; a process that has already started JAX's
 runtime must not fork one, so tests start this script instead of calling
@@ -22,7 +25,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np                                        # noqa: E402
 
-from benchmarks.chip import runner, spec                  # noqa: E402
+from benchmarks.chip import families, runner, spec        # noqa: E402
 
 SMALL = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
              num_key_value_heads=2, head_dim=16, intermediate_size=128,
@@ -33,9 +36,15 @@ SEED = 2**31 + 17
 LIMIT = 0.06
 
 
-def small_cell(trace: bool) -> spec.Cell:
-    c = spec.config("internlm2-1.8b")
-    c.update(SMALL)
+EXTRA = Path(__file__).resolve().parent / "extra_families"
+
+
+def small_cell(trace: bool, config=None) -> spec.Cell:
+    if config is None:
+        c = spec.config("internlm2-1.8b")
+        c.update(SMALL)
+    else:
+        c = json.loads((EXTRA / f"{config}.json").read_text())
     c["serve"] = {"max_batch": 8, "max_batch_delay_ms": 20.0,
                   "max_new_cap": 16, "prompt_buckets": [16, 32]}
     t = {"generator": "open", "rate_per_s": 3.0, "burst": 4,
@@ -97,10 +106,14 @@ def main() -> int:
     ap.add_argument("--fault", choices=sorted(FAULTS))
     ap.add_argument("--trace", type=int, default=0)
     ap.add_argument("--control", choices=["fp8"])
+    ap.add_argument("--config", choices=["kimi-k2-reduced"])
     args = ap.parse_args()
+    if args.config:
+        # the shard is forked from here and finds the family as this does
+        families.__path__.append(str(EXTRA))
     result = runner.run("small", SEED, 2.0, bool(args.trace),
                         t_start=T_START, platform="cpu",
-                        cell=small_cell(bool(args.trace)),
+                        cell=small_cell(bool(args.trace), args.config),
                         hook=FAULTS.get(args.fault), control=args.control)
     print(json.dumps(result), flush=True)
     return 0
