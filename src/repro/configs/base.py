@@ -79,7 +79,6 @@ class ModelConfig:
     seq_parallel: bool = False  # Megatron-SP: residual stream sharded over
                                 # the model axis between blocks (all-reduce
                                 # -> reduce-scatter + all-gather)
-    fuse_ffn: bool = True
     fuse_kv: bool = True        # K/V projections fused via a stacked leading
                                 # axis (never concat across the head dim:
                                 # that miscompiles when heads are sharded)

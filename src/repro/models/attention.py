@@ -231,8 +231,8 @@ def _project_kv(params, x, cfg: ModelConfig):
     concatenated along heads: the kv-head axis of both halves stays
     aligned with its "kv" shards, so the k/v split is always shard-local.
     Fusing Q in as well would require a concat across the *differing* head
-    counts (H vs KVH under GQA) -- the concat+split-across-a-sharded-dim
-    pattern that miscompiled fuse_ffn under GSPMD -- so Q stays separate.
+    counts (H vs KVH under GQA) -- a concat+split across a sharded dim,
+    which GSPMD has miscompiled -- so Q stays separate.
     """
     cd = jnp.dtype(cfg.compute_dtype)
     if cfg.fuse_kv:

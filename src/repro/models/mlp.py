@@ -25,18 +25,8 @@ def mlp_params(mk, cfg: ModelConfig, stacked=(), d_ff=None):
 def mlp(params, x, cfg: ModelConfig):
     cd = jnp.dtype(cfg.compute_dtype)
     act = _act(cfg.act)
-    if cfg.fuse_ffn:
-        # single fused input matmul: better MXU utilization, one gather of x.
-        # Fused along a new leading axis (not concatenated along ff): the
-        # ff dim of both halves stays aligned with its TP shards, so the
-        # gate/up split is always shard-local (concat+split across the
-        # sharded ff dim miscompiles under GSPMD on some XLA builds).
-        wi = jnp.stack([params["wi_gate"], params["wi_up"]]).astype(cd)
-        gu = jnp.einsum("bsd,gdf->gbsf", x, wi)
-        g, u = gu[0], gu[1]
-    else:
-        g = jnp.einsum("bsd,df->bsf", x, params["wi_gate"].astype(cd))
-        u = jnp.einsum("bsd,df->bsf", x, params["wi_up"].astype(cd))
+    g = jnp.einsum("bsd,df->bsf", x, params["wi_gate"].astype(cd))
+    u = jnp.einsum("bsd,df->bsf", x, params["wi_up"].astype(cd))
     h = axisenv.constrain(act(g) * u, "batch", None, "model")
     out = jnp.einsum("bsf,fd->bsd", h, params["wo"].astype(cd))
     return axisenv.constrain(out, "batch",

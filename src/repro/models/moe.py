@@ -126,9 +126,9 @@ def moe_dropping(params, x, cfg: ModelConfig):
 
     # batched gather into expert slots: empty slots hold the OOB index S and
     # read zeros via fill-mode.  (The old pad-row concat grew the token
-    # axis, which is sharded over "model" under seq-parallelism -- the same
-    # concat+split-across-a-sharded-dim pattern that miscompiled fuse_ffn
-    # under GSPMD; a fill-mode gather never changes the sharded shape.)
+    # axis, which is sharded over "model" under seq-parallelism -- a
+    # concat+split across a sharded dim, which GSPMD has miscompiled; a
+    # fill-mode gather never changes the sharded shape.)
     xe = jnp.take_along_axis(
         x, slots.reshape(B, E * C)[..., None], axis=1,
         mode="fill", fill_value=0,

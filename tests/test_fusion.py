@@ -1,17 +1,20 @@
-"""Numerics-agreement regressions for the stacked-axis fusion family.
+"""Numerics-agreement regressions for the projections and dispatch paths
+that GSPMD sharding constrains.
 
-``fuse_ffn`` once miscompiled under GSPMD because the gate/up halves were
-concatenated (then split) across the TP-sharded ff dim; the fix fuses
-along a *new leading axis* so shard boundaries never move.  These tests
-pin the same contract for every fused path the audit touched: fused and
-unfused implementations must agree bit-tightly on the same inputs, and
-the MoE dispatch/combine gathers (now fill-mode instead of pad-row
-concats along sharded dims) must keep matching the one-hot einsum oracle
-even when capacity drops exercise the out-of-bounds fill path.
+A concat (then split) across a TP-sharded dim once miscompiled under
+GSPMD; fused paths stack along a *new leading axis* so shard boundaries
+never move.  These tests pin that contract: fused and unfused K/V
+projections must agree bit-tightly on the same inputs, the gated MLP (two
+input einsums on the weights as passed, no stacked copy) must match a
+float32 numpy reference, and the MoE dispatch/combine gathers (fill-mode
+instead of pad-row concats along sharded dims) must keep matching the
+one-hot einsum oracle even when capacity drops exercise the out-of-bounds
+fill path.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs.base import get_config
 from repro.models import api, moe
@@ -53,15 +56,25 @@ def test_fused_kv_matches_unfused():
                                rtol=1e-6, atol=1e-6)
 
 
-def test_fused_ffn_matches_unfused():
-    cfg = _gqa_cfg()
+_NP_ACT = {
+    "silu": lambda z: z / (1.0 + np.exp(-z)),
+    "gelu": lambda z: 0.5 * z * (1.0 + np.tanh(
+        np.sqrt(2.0 / np.pi) * (z + 0.044715 * z ** 3))),
+}
+
+
+@pytest.mark.parametrize("act", ["silu", "gelu"])
+def test_mlp_matches_float32_reference(act):
+    cfg = _gqa_cfg(act=act)
     params = mlp_params(_ParamMaker(), cfg)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 8, cfg.d_model),
                           jnp.float32)
-    yf = mlp(params, x, cfg.replace(fuse_ffn=True))
-    yu = mlp(params, x, cfg.replace(fuse_ffn=False))
-    np.testing.assert_allclose(np.asarray(yf), np.asarray(yu),
-                               rtol=1e-6, atol=1e-6)
+    y = mlp(params, x, cfg)
+    xn = np.asarray(x, np.float64)
+    wg, wu, wo = (np.asarray(params[k], np.float64)
+                  for k in ("wi_gate", "wi_up", "wo"))
+    ref = (_NP_ACT[act](xn @ wg) * (xn @ wu)) @ wo
+    np.testing.assert_allclose(np.asarray(y), ref, rtol=1e-5, atol=1e-5)
 
 
 def _moe_setup(cf):

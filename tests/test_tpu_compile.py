@@ -77,13 +77,19 @@ def _memory_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-def test_internlm2_full_width_prefill_compiles(one_chip):
+def _internlm2_prefill(sharding, B=32, S=128):
+    """The full-width internlm2-1.8b prefill of a (B, S) batch:
+    (config, lowered program)."""
     cfg = get_config("internlm2-1.8b")
-    params = _shapes(api.abstract_params(cfg), one_chip)
-    tokens = jax.ShapeDtypeStruct((8, 128), jnp.int32, sharding=one_chip)
+    params = _shapes(api.abstract_params(cfg), sharding)
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=sharding)
     prefill = jax.jit(lambda p, t: api.prefill(p, cfg, {"tokens": t}))
-    compiled = prefill.lower(params, tokens).compile()
-    assert _memory_bytes(compiled) < V5E_HBM_BYTES
+    return cfg, prefill.lower(params, tokens)
+
+
+def test_internlm2_full_width_prefill_compiles(one_chip):
+    _, lowered = _internlm2_prefill(one_chip, B=8)
+    assert _memory_bytes(lowered.compile()) < V5E_HBM_BYTES
 
 
 def test_internlm2_full_width_decode_step_fits_one_chip(one_chip):
@@ -104,8 +110,8 @@ _NO_WRITE = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
 
 
 def _writes(hlo: str) -> list:
-    """(opcode, dtype, element count, name of the called computation) of
-    every array an optimized module writes to memory: the outputs of the
+    """(opcode, dtype, shape, name of the called computation) of every
+    array an optimized module writes to memory: the outputs of the
     instructions outside fusion bodies."""
     out, comp, fused = [], None, False
     for line in hlo.splitlines():
@@ -118,8 +124,8 @@ def _writes(hlo: str) -> list:
             continue
         calls = re.search(r"calls=%([\w.\-]+)", line)
         for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", m.group(1)):
-            n = math.prod(int(d) for d in dims.split(",") if d)
-            out.append((m.group(2), dtype, n, calls and calls.group(1)))
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            out.append((m.group(2), dtype, shape, calls and calls.group(1)))
     return out
 
 
@@ -129,26 +135,38 @@ def _root_op(hlo: str, computation: str) -> str:
     return root.group(1)
 
 
+QWEN3_B, QWEN3_R = 32, 192               # the served batch and cache
+
+
+def _qwen3_decode_step(sharding):
+    """The served decode step at qwen3-8b's widths, 2 layers, cache
+    donated: (config, lowered program)."""
+    cfg = get_config("qwen3-8b").replace(num_layers=2)
+    params = _shapes(api.abstract_params(cfg), sharding)
+    cache = _shapes(
+        jax.eval_shape(lambda: api.init_cache(cfg, QWEN3_B, QWEN3_R)),
+        sharding)
+    tok = jax.ShapeDtypeStruct((QWEN3_B, 1), jnp.int32, sharding=sharding)
+    cur = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
+    decode = jax.jit(lambda p, c, t, n: api.decode_step(p, cfg, c, t, n),
+                     donate_argnums=1)
+    return cfg, decode.lower(params, cache, tok, cur)
+
+
 def test_qwen3_decode_step_reads_the_kv_cache_in_place(one_chip):
     """The served decode step at qwen3-8b's widths and the served cache
     geometry (B=32, reserve 192), compiled for the chip: the cache is
     aliased to the step's output, and per layer no op writes an f32 or
     group-repeated copy of the layer's K/V, and the only ops that write
     the layer-stacked cache are in-place updates of the new position."""
-    cfg = get_config("qwen3-8b").replace(num_layers=2)
-    B, R = 32, 192
+    cfg, lowered = _qwen3_decode_step(one_chip)
+    B, R = QWEN3_B, QWEN3_R
     KVH, hd, H = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.num_heads
-    params = _shapes(api.abstract_params(cfg), one_chip)
-    cache = _shapes(jax.eval_shape(lambda: api.init_cache(cfg, B, R)),
-                    one_chip)
-    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
-    cur = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    decode = jax.jit(lambda p, c, t, n: api.decode_step(p, cfg, c, t, n),
-                     donate_argnums=1)
-    hlo = decode.lower(params, cache, tok, cur).compile().as_text()
+    hlo = lowered.compile().as_text()
     assert "input_output_alias" in hlo
     layer, stack = B * R * KVH * hd, cfg.num_layers * B * R * KVH * hd
-    writes = _writes(hlo)
+    writes = [(op, dt, math.prod(shape), calls)
+              for op, dt, shape, calls in _writes(hlo)]
     assert not [w for w in writes if w[1] == "f32" and w[2] == layer]
     assert not [w for w in writes if w[2] == B * R * H * hd]
     stacked = [w for w in writes if w[2] == stack]
@@ -156,3 +174,19 @@ def test_qwen3_decode_step_reads_the_kv_cache_in_place(one_chip):
     assert all(w[0] == "fusion"
                and _root_op(hlo, w[3]) == "dynamic-update-slice"
                for w in stacked), stacked
+
+
+@pytest.mark.parametrize("program", [_qwen3_decode_step, _internlm2_prefill],
+                         ids=["qwen3-decode", "internlm2-prefill"])
+def test_ffn_weights_are_read_in_place(one_chip, program):
+    """The gated MLP's matmuls read each layer's gate and up weights out
+    of the layer-stacked params where they lie: no op writes an array of
+    one layer's (d_model, d_ff) weight shape, alone or stacked in pairs.
+    Shapes, not element counts: the internlm2 prefill's [32,128,8192]
+    activation holds exactly 2 x d_model x d_ff elements."""
+    cfg, lowered = program(one_chip)
+    d, f = cfg.d_model, cfg.d_ff
+    weight = {(d, f), (1, d, f), (2, d, f)}
+    copies = [w for w in _writes(lowered.compile().as_text())
+              if w[2] in weight]
+    assert not copies, copies
