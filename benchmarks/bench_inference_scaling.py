@@ -6,7 +6,7 @@ Server as worker count grows -- the paper's point is that the VS keeps
 transfer time flat because large results stop flowing through the queue
 path.
 
-Simulation caveat (documented in EXPERIMENTS.md): workers are threads on
+Simulation caveat: workers are threads on
 one CPU, so Fig. 7 cannot show real multi-node speedup; the *relative*
 VS-vs-no-VS transfer behaviour (Fig. 8) is the reproducible claim.
 """

@@ -14,7 +14,7 @@ identical exactly-once transport.
 The engine is the reduced reference model, built *inside* the shard
 child (this parent process never imports jax).  A warmup wave per arm
 pays the jit compiles before the clock starts, so the rows report warm
-steady-state -- the same honesty rule as ``Engine.throughput()``.
+steady-state: no compile is counted as serving time.
 """
 from __future__ import annotations
 

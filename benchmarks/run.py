@@ -17,8 +17,8 @@ import traceback
 def main() -> None:
     full = "--full" in sys.argv
     from benchmarks import (bench_discovery, bench_envelope,
-                            bench_inference_scaling, bench_roofline,
-                            bench_task_overhead, bench_value_server)
+                            bench_inference_scaling, bench_task_overhead,
+                            bench_value_server)
 
     suites = [
         ("task_overhead (Fig 5)", bench_task_overhead.run,
@@ -32,7 +32,6 @@ def main() -> None:
          {} if full else {"T_per_worker": 4}),
         ("discovery (Fig 4)", bench_discovery.run,
          {} if full else {"num_molecules": 600, "qc_budget": 48}),
-        ("roofline (dry-run)", bench_roofline.run, {}),
     ]
     print("name,value,derived")
     failed = []

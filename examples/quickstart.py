@@ -67,10 +67,8 @@ def serve_demo():
     eng = Engine(cfg, params, max_new=8)
     prompts = np.random.default_rng(0).integers(
         0, cfg.vocab_size, size=(2, 16), dtype=np.int32)
-    eng.generate(prompts)                   # warmup: compiles the bucket
     out = eng.generate(prompts)
-    print(f"   generated {out.shape} ({eng.throughput():.0f} tok/s "
-          "steady-state)\n")
+    print(f"   generated {out.shape}\n")
 
 
 if __name__ == "__main__":

@@ -1,4 +1,4 @@
-"""Configuration system: model / shape / sharding / train configs + registry.
+"""Configuration system: model / sharding / train configs + registry.
 
 Every assigned architecture gets a module ``src/repro/configs/<id>.py`` that
 exposes ``CONFIG`` (the exact published configuration) and ``reduced()`` (a
@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Optional, Tuple
 
 
@@ -67,18 +67,11 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
 
     # Implementation switches (perf levers; do not change semantics)
-    attn_impl: str = "ref"      # ref (jnp, chunked) | kernel (pallas, TPU)
     attn_chunk: int = 1024      # KV chunk for the chunked-ref path
     moe_impl: str = "dropping"  # dense | dropping (capacity-based EP dispatch)
     remat: str = "block"        # none | block (recompute everything) |
                                 # policy (save matmul outputs, recompute
                                 # elementwise only -- cheaper backward)
-    scan_layers: bool = True    # stack layers with lax.scan (small HLO)
-    scan_unroll: bool = False   # fully unroll scans (dry-run cost probes:
-                                # XLA cost_analysis counts while bodies once)
-    seq_parallel: bool = False  # Megatron-SP: residual stream sharded over
-                                # the model axis between blocks (all-reduce
-                                # -> reduce-scatter + all-gather)
     fuse_kv: bool = True        # K/V projections fused via a stacked leading
                                 # axis (never concat across the head dim:
                                 # that miscompiles when heads are sharded)
@@ -104,37 +97,6 @@ class ModelConfig:
 
 
 # ---------------------------------------------------------------------------
-# Input shapes (assigned): every arch is paired with these four shapes.
-# ---------------------------------------------------------------------------
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    kind: str          # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES = {
-    "train_4k":    ShapeConfig("train_4k",    "train",   4_096,   256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32_768,  32),
-    "decode_32k":  ShapeConfig("decode_32k",  "decode",  32_768,  128),
-    "long_500k":   ShapeConfig("long_500k",   "decode",  524_288, 1),
-}
-
-# long_500k requires sub-quadratic context handling: run only for SSM /
-# hybrid / linear-attention families (see DESIGN.md §4).
-LONG_CONTEXT_FAMILIES = ("hybrid", "ssm")
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """Whether (arch, shape) is a valid dry-run cell; reason when not."""
-    if shape.name == "long_500k" and cfg.family not in LONG_CONTEXT_FAMILIES:
-        return False, "long_500k skipped: full-attention arch (sub-quadratic required)"
-    return True, ""
-
-
-# ---------------------------------------------------------------------------
 # Sharding configuration
 # ---------------------------------------------------------------------------
 
@@ -142,9 +104,6 @@ def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
 class ShardingConfig:
     mode: str = "dp_tp"        # dp_tp (params replicated over data) | fsdp_tp
     zero: int = 1              # 0: opt state like params; 1: opt sharded over data
-    shard_cache_seq: bool = True   # decode: shard KV cache sequence over model axis
-    grad_compress: str = "none"    # none | bf16 | int8_ef (cross-pod hop)
-    remat_override: Optional[str] = None
     microbatches: int = 1      # gradient accumulation steps
 
 
@@ -193,7 +152,7 @@ def list_archs():
 
 
 # ---------------------------------------------------------------------------
-# Analytic parameter / FLOP accounting (used by roofline + sanity tests)
+# Analytic parameter accounting
 # ---------------------------------------------------------------------------
 
 def param_count(cfg: ModelConfig) -> int:
@@ -241,32 +200,3 @@ def param_count(cfg: ModelConfig) -> int:
         total += cfg.vocab_size * d      # lm head
     total += d                           # final norm
     return int(total)
-
-
-def active_param_count(cfg: ModelConfig) -> int:
-    """Active params per token (MoE: top-k experts only)."""
-    if not cfg.is_moe:
-        return param_count(cfg)
-    dense_like = param_count(cfg)
-    gated = cfg.act in ("silu", "gelu")
-    per_expert = (3 if gated else 2) * cfg.d_model * cfg.d_ff
-    layers = cfg.num_layers + cfg.encoder_layers
-    inactive = layers * (cfg.num_experts - cfg.num_experts_per_token) * per_expert
-    return int(dense_like - inactive)
-
-
-def model_flops_per_token(cfg: ModelConfig, seq_len: int, training: bool) -> float:
-    """MODEL_FLOPS/token = 6*N_active (train) or 2*N_active (fwd) + attention."""
-    n = active_param_count(cfg) - cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2)
-    mult = 6.0 if training else 2.0
-    flops = mult * n
-    # attention score flops: 2 * 2 * seq * qo per token (causal halves it)
-    if not cfg.is_attention_free:
-        qo = cfg.num_heads * cfg.resolved_head_dim
-        window = seq_len
-        if cfg.sliding_window and not cfg.local_global_period:
-            window = min(seq_len, cfg.sliding_window)
-        flops += mult / 1.5 * 2 * qo * (window / 2)
-    # lm head
-    flops += mult * cfg.d_model * cfg.vocab_size
-    return flops

@@ -4,7 +4,7 @@ MoE per the assignment's paper table [arXiv:2501.kimi2; unverified].
 
 Note: the released Kimi K2 uses MLA attention; the assignment table
 specifies GQA kv=8, which is what we implement (the assignment config is
-authoritative for the dry-run)."""
+authoritative here)."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(

@@ -1,9 +1,9 @@
 """qwen2-vl-72b [vlm]: 80L d_model=8192 64H (GQA kv=8) d_ff=29568
 vocab=152064 — M-RoPE, dynamic resolution [arXiv:2409.12191; hf].
 
-Backbone only per the assignment: the vision frontend is a stub and
-``input_specs()`` provides precomputed patch embeddings plus (3, B, S)
-M-RoPE positions (temporal / height / width)."""
+Backbone only per the assignment: the vision frontend is a stub; the
+batch carries precomputed patch embeddings (``embeds``) plus (3, B, S)
+M-RoPE ``positions`` (temporal / height / width)."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(

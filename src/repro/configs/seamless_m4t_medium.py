@@ -1,8 +1,8 @@
 """seamless-m4t-medium [audio]: enc-dec, 12+12L d_model=1024 16H (kv=16)
 d_ff=4096 vocab=256206 [arXiv:2308.11596; hf].
 
-Backbone only per the assignment: the speech frontend is a stub and
-``input_specs()`` provides precomputed frame embeddings for the encoder."""
+Backbone only per the assignment: the speech frontend is a stub; the
+batch carries precomputed frame embeddings (``frames``) for the encoder."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(

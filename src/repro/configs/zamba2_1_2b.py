@@ -3,9 +3,9 @@ vocab=32000, ssm_state=64 — Mamba2 backbone + shared attention block
 [arXiv:2411.15242; hf].
 
 The shared attention block (one parameter set, applied after every
-`attn_every` Mamba2 layers) is the Zamba2 signature; see DESIGN.md for the
-simplifications vs. the released checkpoints (no LoRA adapters per
-application, single shared block instead of two alternating)."""
+`attn_every` Mamba2 layers) is the Zamba2 signature.  Simplifications vs.
+the released checkpoints: no LoRA adapters per application, and a single
+shared block instead of two alternating."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(

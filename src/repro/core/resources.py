@@ -6,9 +6,9 @@ release slots concurrently; an Allocator agent moves slots between pools
 ("different colored traffic lights" in the paper's Fig. 2).  Reallocation
 of *busy* slots is deferred: the slots transfer as they are released.
 
-On the TPU adaptation a slot is a mesh slice (DESIGN.md §2); the quantum of
-reallocation is the largest slice a task type needs, exactly as the paper
-reallocates Theta nodes in 4-node increments.
+On the TPU adaptation a slot is a mesh slice (a chip or a group of
+chips); the quantum of reallocation is the largest slice a task type
+needs, exactly as the paper reallocates Theta nodes in 4-node increments.
 """
 from __future__ import annotations
 

@@ -35,7 +35,7 @@ per-key linearizability the locked implementation had (a concurrent
 ``get`` of a key mid-spill waits and then faults it back; it can never
 observe the key missing).
 
-TPU adaptation note (DESIGN.md §2): on a real pod the store holds
+TPU adaptation note: on a real pod the store holds
 device-resident jax.Arrays and resolution is a device-to-device copy; in
 this container the store is an in-process dict with a configurable
 simulated fetch bandwidth so SynApp can reproduce the paper's Fig. 5/6

@@ -1,8 +1,8 @@
 """Ambient activation-sharding environment.
 
-Model code is mesh-agnostic; the step builder (launch/steps.build_program)
-installs this environment around tracing so that models can pin key
-activations with logical constraints:
+Model code is mesh-agnostic; the train-step builder
+(launch/steps.build_program) installs this environment around tracing so
+that models can pin key activations with logical constraints:
 
     x = axisenv.constrain(x, "batch", None, "model", None)
 
@@ -62,9 +62,7 @@ def resolve(logical, dim: int):
             ax = env["batch"]
             return ax if len(ax) > 1 else ax[0]
         return None
-    if logical in ("model", "kv", "seq"):
-        # "seq": sequence-parallel residual sharding also lands on the
-        # model axis (between-block tokens are independent across TP ranks)
+    if logical in ("model", "kv"):
         if env["model"] and dim % env["model_size"] == 0:
             return env["model"]
         return None

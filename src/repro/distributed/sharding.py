@@ -14,11 +14,9 @@ mesh axis falls back to replication (e.g. granite's single KV head).
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 # logical axis -> preferred mesh axis (dp_tp mode)
 TP_RULES = {
@@ -39,10 +37,6 @@ FSDP_AXES = ("embed", "embed2", "ff", "head_dim", "vocab", "experts")
 # parameters smaller than this stay replicated in fsdp mode (norm scales,
 # biases -- sharding them only adds collective launches)
 FSDP_MIN_SIZE = 1 << 16
-
-
-def mesh_axis_size(mesh: Mesh, name: Optional[str]) -> int:
-    return int(mesh.shape[name]) if name and name in mesh.shape else 1
 
 
 def spec_for(axes, shape, mesh: Mesh, mode: str = "dp_tp") -> P:
@@ -88,11 +82,6 @@ def tree_specs(spec_tree, shape_tree, mesh: Mesh, mode: str = "dp_tp"):
             isinstance(a, (str, type(None))) for a in x))
 
 
-def tree_shardings(spec_tree, shape_tree, mesh: Mesh, mode: str = "dp_tp"):
-    return jax.tree.map(lambda p: NamedSharding(mesh, p),
-                        tree_specs(spec_tree, shape_tree, mesh, mode))
-
-
 # ---------------------------------------------------------------------------
 # Batch / activation sharding
 # ---------------------------------------------------------------------------
@@ -110,33 +99,6 @@ def batch_axes(mesh: Mesh, global_batch: int, mode: str = "dp_tp"):
             axes.append(ax)
             rem //= mesh.shape[ax]
     return tuple(axes)
-
-
-def batch_spec(mesh: Mesh, global_batch: int, extra_dims: int = 1) -> P:
-    """PartitionSpec for a (B, ...) array: batch over (pod,data), rest None."""
-    ax = batch_axes(mesh, global_batch)
-    lead = ax if ax else None
-    return P(lead, *([None] * extra_dims))
-
-
-def cache_spec(axes, shape, mesh: Mesh, global_batch: int) -> P:
-    """KV-cache / state sharding: batch dim over (pod,data), model dims per
-    TP rules.  `axes` uses logical names with 'batch' marking the batch dim."""
-    out = []
-    used = set()
-    bax = batch_axes(mesh, global_batch)
-    for name, dim in zip(axes, shape):
-        if name == "batch" and bax and all(a not in used for a in bax):
-            out.append(bax if len(bax) > 1 else bax[0])
-            used.update(bax)
-            continue
-        m = TP_RULES.get(name)
-        if m and m in mesh.shape and m not in used and dim % mesh.shape[m] == 0:
-            out.append(m)
-            used.add(m)
-        else:
-            out.append(None)
-    return P(*out)
 
 
 # ---------------------------------------------------------------------------

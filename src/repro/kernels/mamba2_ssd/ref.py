@@ -62,12 +62,10 @@ def _segsum(log_a):
     return jnp.where(mask, diff, -jnp.inf)
 
 
-def ssd_chunked(x, log_a, b, c, initial_state=None, chunk: int = 128,
-                unroll: bool = False):
+def ssd_chunked(x, log_a, b, c, initial_state=None, chunk: int = 128):
     """Chunked SSD: quadratic intra-chunk attention + inter-chunk recurrence.
 
     Identical numerics target as ssd_naive; O(L/Q) sequential steps.
-    `unroll` unrolls the inter-chunk scan (dry-run cost probes).
     """
     B, L, H, P = x.shape
     N = b.shape[-1]
@@ -104,7 +102,7 @@ def ssd_chunked(x, log_a, b, c, initial_state=None, chunk: int = 128,
         return s, s_in
 
     xs = (jnp.moveaxis(chunk_state, 1, 0), jnp.moveaxis(total, 1, 0))
-    s_final, s_prevs = jax.lax.scan(step, s0, xs, unroll=unroll)
+    s_final, s_prevs = jax.lax.scan(step, s0, xs)
     s_prev = jnp.moveaxis(s_prevs, 0, 1)                # (B,nc,H,P,N)
 
     # inter-chunk output: y_t += C_t . (decay_in(t) * S_prev)

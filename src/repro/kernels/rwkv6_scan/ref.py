@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 
-def wkv6_naive(r, k, v, log_w, u, initial_state=None, unroll: bool = False):
+def wkv6_naive(r, k, v, log_w, u, initial_state=None):
     """Step-by-step scan; ground-truth oracle for tests."""
     B, L, H, K = r.shape
     V = v.shape[-1]
@@ -39,12 +39,11 @@ def wkv6_naive(r, k, v, log_w, u, initial_state=None, unroll: bool = False):
         return s, y
 
     xs = tuple(jnp.moveaxis(t, 1, 0) for t in (rf, kf, vf, wf))
-    s, ys = jax.lax.scan(step, s0, xs, unroll=unroll)
+    s, ys = jax.lax.scan(step, s0, xs)
     return jnp.moveaxis(ys, 0, 1).astype(r.dtype), s
 
 
-def wkv6_chunked(r, k, v, log_w, u, initial_state=None, chunk: int = 64,
-                 unroll: bool = False):
+def wkv6_chunked(r, k, v, log_w, u, initial_state=None, chunk: int = 64):
     """Chunked WKV6: sequential scan *within* each chunk (vectorized across
     all chunks, so the sequential depth is Q + L/Q instead of L) plus an
     analytic inter-chunk recurrence.
@@ -69,7 +68,7 @@ def wkv6_chunked(r, k, v, log_w, u, initial_state=None, chunk: int = 64,
           else initial_state.astype(jnp.float32))
 
     # intra-chunk term from zero state, all chunks at once
-    y_intra, chunk_state = wkv6_naive(rf, kf, vf, lw, uf, unroll=unroll)
+    y_intra, chunk_state = wkv6_naive(rf, kf, vf, lw, uf)
     y_intra = y_intra.reshape(B, nc, Q, H, V).astype(jnp.float32)
     chunk_state = chunk_state.reshape(B, nc, H, K, V)
 
@@ -85,7 +84,7 @@ def wkv6_chunked(r, k, v, log_w, u, initial_state=None, chunk: int = 64,
         return s, s_in
 
     xs = (jnp.moveaxis(chunk_state, 1, 0), jnp.moveaxis(total, 1, 0))
-    s_final, s_prevs = jax.lax.scan(step, s0, xs, unroll=unroll)
+    s_final, s_prevs = jax.lax.scan(step, s0, xs)
     s_prev = jnp.moveaxis(s_prevs, 0, 1)                    # (B,nc,H,K,V)
 
     # carry-in contribution: r_t . diag(prod_{s<=t-1} w) S_prev

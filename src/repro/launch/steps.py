@@ -1,14 +1,11 @@
-"""Program builders: train_step / prefill / decode_step + their shardings.
+"""The train step and its sharded program.
 
-This is the single source of truth the dry-run, the trainer, the serving
-engine and the benchmarks all use.  For every (arch x input-shape) cell it
-provides:
-
-- ``input_specs(cfg, shape)``      : ShapeDtypeStruct stand-ins, no allocation
-- ``input_shardings(cfg, shape, mesh)``
-- ``abstract_state(cfg)`` / ``state_shardings(cfg, mesh, sc)``
+- ``init_state(cfg, key)`` / ``abstract_state(cfg)`` / ``state_shardings``
 - ``make_train_step(cfg, tc, sc)`` : grad accumulation, clip, AdamW, guards
-- ``make_prefill(cfg)`` / ``make_decode_step(cfg)``
+- ``build_program(cfg, global_batch, seq_len, mesh)`` : the train step
+  jitted with its state and batch shardings on a mesh
+
+The served prefill and decode programs are built by ``serving.Engine``.
 """
 from __future__ import annotations
 
@@ -18,8 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import (ModelConfig, ShapeConfig, ShardingConfig,
-                                TrainConfig)
+from repro.configs.base import ModelConfig, ShardingConfig, TrainConfig
 from repro.distributed import axisenv, sharding as shd
 from repro.models import api
 from repro.optim import adamw, clip, schedules
@@ -43,35 +39,7 @@ def _with_axisenv(fn, mesh, global_batch, mode="dp_tp"):
 
 
 # ---------------------------------------------------------------------------
-# Cache logical axes (mirrors api.init_cache structure)
-# ---------------------------------------------------------------------------
-
-_KV_AXES = {"k": ("layer", "batch", "seq", "kv_heads", "head_dim"),
-            "v": ("layer", "batch", "seq", "kv_heads", "head_dim")}
-
-
-def cache_axes(cfg: ModelConfig):
-    if cfg.is_encdec:
-        return {"self": dict(_KV_AXES), "cross": dict(_KV_AXES)}
-    if cfg.rwkv:
-        return {
-            "tm_shift": ("layer", "batch", "seq", "embed"),
-            "cm_shift": ("layer", "batch", "seq", "embed"),
-            "state": ("layer", "batch", "heads", "head_dim", "head_dim2"),
-        }
-    if cfg.family == "hybrid":
-        return {
-            "mamba": {
-                "conv": ("layer", "batch", "conv", "ssm_inner"),
-                "ssm": ("layer", "batch", "ssm_heads", "head_dim", "state"),
-            },
-            "attn": dict(_KV_AXES),
-        }
-    return dict(_KV_AXES)
-
-
-# ---------------------------------------------------------------------------
-# Inputs
+# Train batch
 # ---------------------------------------------------------------------------
 
 
@@ -79,8 +47,8 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
 
 
-def batch_specs(cfg: ModelConfig, B: int, S: int, *, with_labels: bool):
-    """Abstract model-input batch for a full-sequence program."""
+def batch_specs(cfg: ModelConfig, B: int, S: int):
+    """Abstract (B, S) train batch: model inputs and labels."""
     cd = cfg.compute_dtype
     out = {}
     if cfg.family == "vlm":
@@ -90,30 +58,12 @@ def batch_specs(cfg: ModelConfig, B: int, S: int, *, with_labels: bool):
         out["tokens"] = _sds((B, S), "int32")
     if cfg.is_encdec:
         out["frames"] = _sds((B, S, cfg.d_model), cd)
-    if with_labels:
-        out["labels"] = _sds((B, S), "int32")
+    out["labels"] = _sds((B, S), "int32")
     return out
 
 
-def input_specs(cfg: ModelConfig, shape: ShapeConfig):
-    """ShapeDtypeStruct stand-ins for every program input of this cell."""
-    B, S = shape.global_batch, shape.seq_len
-    if shape.kind == "train":
-        return {"batch": batch_specs(cfg, B, S, with_labels=True)}
-    if shape.kind == "prefill":
-        return {"batch": batch_specs(cfg, B, S, with_labels=False)}
-    if shape.kind == "decode":
-        cache = jax.eval_shape(
-            lambda: api.init_cache(cfg, B, S, enc_len=S))
-        return {
-            "cache": cache,
-            "tokens": _sds((B, 1), "int32"),
-            "cur_len": _sds((), "int32"),
-        }
-    raise ValueError(shape.kind)
-
-
-def _batch_input_shardings(cfg, specs, mesh, global_batch, mode="dp_tp"):
+def _batch_shardings(specs, mesh, global_batch, mode="dp_tp"):
+    """Each batch input split over the batch axes on its batch dimension."""
     bax = shd.batch_axes(mesh, global_batch, mode)
     lead = bax if bax else None
 
@@ -124,29 +74,6 @@ def _batch_input_shardings(cfg, specs, mesh, global_batch, mode="dp_tp"):
 
     return {name: NamedSharding(mesh, spec_of(name, s))
             for name, s in specs.items()}
-
-
-def input_shardings(cfg: ModelConfig, shape: ShapeConfig, mesh,
-                    mode: str = "dp_tp"):
-    specs = input_specs(cfg, shape)
-    if shape.kind in ("train", "prefill"):
-        return {"batch": _batch_input_shardings(
-            cfg, specs["batch"], mesh, shape.global_batch, mode)}
-    # decode
-    axes = cache_axes(cfg)
-    cache_sh = jax.tree.map(
-        lambda ax, s: NamedSharding(mesh, shd.cache_spec(
-            ax, s.shape, mesh, shape.global_batch)),
-        axes, specs["cache"],
-        is_leaf=lambda x: isinstance(x, tuple) and all(
-            isinstance(a, (str, type(None))) for a in x))
-    bax = shd.batch_axes(mesh, shape.global_batch, mode)
-    lead = bax if bax else None
-    return {
-        "cache": cache_sh,
-        "tokens": NamedSharding(mesh, P(lead, None)),
-        "cur_len": NamedSharding(mesh, P()),
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -252,62 +179,25 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig,
     return train_step
 
 
-def make_prefill(cfg: ModelConfig):
-    def prefill(params, batch):
-        return api.prefill(params, cfg, batch)
-    return prefill
-
-
-def make_decode_step(cfg: ModelConfig):
-    def decode_step(params, cache, tokens, cur_len):
-        return api.decode_step(params, cfg, cache, tokens, cur_len)
-    return decode_step
-
-
 # ---------------------------------------------------------------------------
-# Jitted + sharded program assembly (used by dryrun / trainer / engine)
+# Jitted + sharded program assembly
 # ---------------------------------------------------------------------------
 
 
-def build_program(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
+def build_program(cfg: ModelConfig, global_batch: int, seq_len: int, mesh, *,
                   tc: Optional[TrainConfig] = None,
                   sc: Optional[ShardingConfig] = None):
-    """Returns (jitted_fn, example_args as ShapeDtypeStructs)."""
+    """The sharded train step for a (global_batch, seq_len) batch:
+    (jitted_fn, example_args as ShapeDtypeStructs)."""
     tc = tc or TrainConfig()
     sc = sc or ShardingConfig()
-    specs = input_specs(cfg, shape)
-    in_sh = input_shardings(cfg, shape, mesh, sc.mode)
+    specs = batch_specs(cfg, global_batch, seq_len)
     st_sh = state_shardings(cfg, mesh, sc)
-
-    if shape.kind == "train":
-        fn = _with_axisenv(make_train_step(cfg, tc, sc), mesh,
-                           shape.global_batch, sc.mode)
-        jfn = jax.jit(fn,
-                      in_shardings=(st_sh, in_sh["batch"]),
-                      out_shardings=(st_sh, None),
-                      donate_argnums=(0,))
-        args = (abstract_state(cfg), specs["batch"])
-        return jfn, args
-
-    if shape.kind == "prefill":
-        fn = _with_axisenv(make_prefill(cfg), mesh, shape.global_batch,
-                           sc.mode)
-        jfn = jax.jit(fn,
-                      in_shardings=(st_sh["params"], in_sh["batch"]),
-                      out_shardings=None)
-        args = (api.abstract_params(cfg), specs["batch"])
-        return jfn, args
-
-    if shape.kind == "decode":
-        fn = _with_axisenv(make_decode_step(cfg), mesh, shape.global_batch,
-                           sc.mode)
-        jfn = jax.jit(fn,
-                      in_shardings=(st_sh["params"], in_sh["cache"],
-                                    in_sh["tokens"], in_sh["cur_len"]),
-                      out_shardings=(None, in_sh["cache"]),
-                      donate_argnums=(1,))
-        args = (api.abstract_params(cfg), specs["cache"], specs["tokens"],
-                specs["cur_len"])
-        return jfn, args
-
-    raise ValueError(shape.kind)
+    fn = _with_axisenv(make_train_step(cfg, tc, sc), mesh, global_batch,
+                       sc.mode)
+    jfn = jax.jit(fn,
+                  in_shardings=(st_sh, _batch_shardings(
+                      specs, mesh, global_batch, sc.mode)),
+                  out_shardings=(st_sh, None),
+                  donate_argnums=(0,))
+    return jfn, (abstract_state(cfg), specs)

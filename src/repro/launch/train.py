@@ -1,8 +1,8 @@
 """Training driver: any assigned arch, checkpoint/restart, metrics.
 
 Runs on whatever mesh is ambient -- the CPU host mesh for examples/smoke
-and the production meshes on a real pod (same code path as the dry-run's
-train program).  Demonstrates the fault-tolerance loop: async checkpoints
+and a device mesh on a pod (the same train step ``steps.build_program``
+shards).  Demonstrates the fault-tolerance loop: async checkpoints
 every --ckpt-every steps, `--resume` restores the newest valid checkpoint
 and the deterministic step-keyed data stream realigns automatically.
 

@@ -46,7 +46,8 @@ def init_params(cfg: ModelConfig, key):
 
 
 def abstract_params(cfg: ModelConfig):
-    """ShapeDtypeStruct tree without allocating (for dry-runs)."""
+    """ShapeDtypeStruct tree without allocating (for ahead-of-time
+    lowering)."""
     return jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0)))
 

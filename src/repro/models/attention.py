@@ -12,7 +12,7 @@ only their window), and supports:
 - dynamic valid-length masking (decode against a partially filled cache).
 
 The TPU Pallas kernel (`repro.kernels.flash_attention`) implements the same
-contract; `attend` dispatches on ``cfg.attn_impl``.
+contract; `attend` runs the reference until a kernel wins a measured cell.
 """
 from __future__ import annotations
 
@@ -204,16 +204,7 @@ def _decode_attention(qf, k, v, mask, softcap):
 
 def attend(q, k, v, *, cfg: ModelConfig, causal=True, window=None,
            q_offset=0, valid_len=None):
-    """Dispatch between the jnp reference and the Pallas TPU kernel."""
-    if cfg.attn_impl == "kernel":
-        from repro.kernels.flash_attention import ops as fa_ops
-        # The Pallas kernel covers the static-offset self/cross attention
-        # cases; decode (dynamic offset, Sq=1) always uses the reference
-        # (it is a tiny GEMV-like op where a kernel buys nothing).
-        if isinstance(q_offset, int) and valid_len is None and q.shape[1] > 1:
-            return fa_ops.flash_attention(
-                q, k, v, causal=causal, window=window,
-                softcap=cfg.attn_logit_softcap, q_offset=q_offset)
+    """Attention with the model's softcap and chunk size."""
     return mha_reference(
         q, k, v, causal=causal, window=window,
         softcap=cfg.attn_logit_softcap, q_offset=q_offset,
@@ -265,10 +256,7 @@ def output_proj(params, o, cfg: ModelConfig):
     cd = jnp.dtype(cfg.compute_dtype)
     o = axisenv.constrain(o, "batch", None, "model", None)
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(cd))
-    # under sequence parallelism the partial-sum reduction lands directly
-    # as a reduce-scatter onto the token-sharded residual layout
-    return axisenv.constrain(out, "batch",
-                             "seq" if cfg.seq_parallel else None, None)
+    return axisenv.constrain(out, "batch", None, None)
 
 
 def self_attention(params, x, cfg: ModelConfig, *, cos, sin, causal=True,

@@ -44,8 +44,7 @@ def cross_kv(params, enc_out, cfg: ModelConfig):
         kv = attn.encode_cross_kv(p["cross"], enc_out, cfg)
         return None, kv
 
-    _, kv = jax.lax.scan(body, None, params["decoder"],
-                         unroll=cfg.scan_unroll)
+    _, kv = jax.lax.scan(body, None, params["decoder"])
     return kv
 
 
@@ -63,6 +62,5 @@ def run_decoder(params, h, cfg: ModelConfig, *, cos, sin, enc_kv,
     body = _ckpt(body, cfg)
     (h, aux), new_cache = jax.lax.scan(
         body, (h, jnp.zeros((), jnp.float32)),
-        (params["decoder"], enc_kv, cache), length=cfg.num_layers,
-        unroll=cfg.scan_unroll)
+        (params["decoder"], enc_kv, cache), length=cfg.num_layers)
     return h, new_cache, aux

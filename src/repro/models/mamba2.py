@@ -120,10 +120,8 @@ def mamba_block(params, x, cfg: ModelConfig, cache=None):
         y, s = ssd_ops.ssd_step(xh[:, 0], log_a[:, 0], bg[:, 0], cg[:, 0], s0)
         y = y[:, None]
     else:
-        impl = "kernel" if cfg.attn_impl == "kernel" else "ref"
-        y, s = ssd_ops.ssd(xh, log_a.astype(cd), bg, cg, s0, impl=impl,
-                           chunk=min(cfg.attn_chunk, 128),
-                           unroll=cfg.scan_unroll)
+        y, s = ssd_ops.ssd(xh, log_a.astype(cd), bg, cg, s0,
+                           chunk=min(cfg.attn_chunk, 128))
 
     y = y.astype(jnp.float32) + (params["d_skip"].astype(jnp.float32)[:, None]
                                  * xin_c.astype(jnp.float32).reshape(B, L, H, P))

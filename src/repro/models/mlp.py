@@ -29,5 +29,4 @@ def mlp(params, x, cfg: ModelConfig):
     u = jnp.einsum("bsd,df->bsf", x, params["wi_up"].astype(cd))
     h = axisenv.constrain(act(g) * u, "batch", None, "model")
     out = jnp.einsum("bsf,fd->bsd", h, params["wo"].astype(cd))
-    return axisenv.constrain(out, "batch",
-                             "seq" if cfg.seq_parallel else None, None)
+    return axisenv.constrain(out, "batch", None, None)

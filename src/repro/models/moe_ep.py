@@ -2,8 +2,7 @@
 
 Why: under pure GSPMD the gather-based dispatch/combine lowers to
 all-gathers of the (E, C, D) expert buffers plus a giant scatter-add
-all-reduce in the backward pass (~94 GB/layer/device wire for kimi-k2 at
-train_4k -- measured, see EXPERIMENTS.md §Perf).  The canonical EP lowering
+all-reduce in the backward pass.  The canonical EP lowering
 moves only the routed token activations, twice:
 
   tokens (seq-sharded over the model axis)
@@ -13,10 +12,10 @@ moves only the routed token activations, twice:
     -> results written back into the mirrored slot layout
     -> all_to_all back (combine) -> weighted sum per token.
 
-Per-layer wire: 2 x T_local*K*D*bf16 per device (~0.9 GB for kimi) instead
-of ~94 GB.  Works with the seq-parallel residual layout (tokens already
-sharded over "model"); requires S % TP == 0, falling back to the GSPMD path
-otherwise (e.g. decode with S=1).
+Per-layer wire: 2 x T_local*K*D*bf16 per device instead of the whole
+expert buffers.  The shard_map takes the tokens sequence-sharded over
+"model"; requires S % TP == 0, falling back to the GSPMD path otherwise
+(e.g. decode with S=1).
 """
 from __future__ import annotations
 

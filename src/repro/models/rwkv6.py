@@ -1,7 +1,7 @@
 """RWKV6 "Finch" block: token-shift time mix with data-dependent decay
 (WKV recurrence in repro.kernels.rwkv6_scan) + channel mix FFN.
 
-Simplifications vs. the released RWKV6 (noted in DESIGN.md): the five
+Simplifications vs. the released RWKV6: the five
 token-shift mixing coefficients are static learned vectors (the low-rank
 data-dependent ddlerp is applied only to the decay, which is the part that
 changes the recurrence class); the decay LoRA has rank cfg.rwkv_lora.
@@ -98,10 +98,8 @@ def rwkv_time_mix(params, x, cfg: ModelConfig, cache=None):
                                  params["u"], state)
         y = y[:, None]
     else:
-        impl = "kernel" if cfg.attn_impl == "kernel" else "ref"
         y, s = wkv_ops.wkv6(r, k, v, log_w.astype(cd), params["u"], state,
-                            impl=impl, chunk=min(cfg.attn_chunk, 64),
-                            unroll=cfg.scan_unroll)
+                            chunk=min(cfg.attn_chunk, 64))
 
     y = y.reshape(B, L, D)
     y = rmsnorm({"scale": params["ln_x"]}, y, cfg.norm_eps) * g

@@ -77,15 +77,10 @@ def _maybe_post(p, name, y, cfg):
     return rmsnorm(p[name], y, cfg.norm_eps) if cfg.post_norm else y
 
 
-def _residual(h, cfg):
-    """Between-block residual-stream sharding.  With seq_parallel the token
-    dimension is sharded over the model axis, so the per-sub-layer
-    all-reduce of TP partial sums becomes reduce-scatter (+ all-gather at
-    the next projection): half the wire bytes, and norms/elementwise run
-    1/TP as wide."""
+def _residual(h):
+    """Between-block residual stream: batch-sharded, replicated over the
+    model axis."""
     from repro.distributed import axisenv
-    if cfg.seq_parallel:
-        return axisenv.constrain(h, "batch", "seq", None)
     return axisenv.constrain(h, "batch", None, None)
 
 
@@ -102,12 +97,12 @@ def apply_dense_block(p, h, cfg: ModelConfig, *, cos, sin, window=None,
         a_out, new_cache = attn.self_attention(
             p["attn"], a_in, cfg, cos=cos, sin=sin, causal=causal,
             window=window, cache=cache, cur_len=cur_len, layer=layer)
-    h = _residual(h + _maybe_post(p, "ln1_post", a_out, cfg), cfg)
+    h = _residual(h + _maybe_post(p, "ln1_post", a_out, cfg))
 
     if enc_kv is not None:
         c_in = rmsnorm(p["ln_cross"], h, cfg.norm_eps)
         h = _residual(h + attn.cross_attention(p["cross"], c_in, enc_kv,
-                                               cfg), cfg)
+                                               cfg))
 
     m_in = rmsnorm(p["ln2"], h, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
@@ -115,7 +110,7 @@ def apply_dense_block(p, h, cfg: ModelConfig, *, cos, sin, window=None,
         m_out, aux = moe_ffn(p["ffn"], m_in, cfg)
     else:
         m_out = mlp(p["ffn"], m_in, cfg)
-    h = _residual(h + _maybe_post(p, "ln2_post", m_out, cfg), cfg)
+    h = _residual(h + _maybe_post(p, "ln2_post", m_out, cfg))
     return h, new_cache, aux
 
 
@@ -195,12 +190,11 @@ def _scan_uniform(params, h, cfg, apply_fn, cache, collect):
     if cache is None and not collect:
         xs = (params, None)
     (h, aux), new_cache = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)),
-                                       xs, length=L,
-                                       unroll=cfg.scan_unroll)
+                                       xs, length=L)
     return h, new_cache, aux
 
 
-def _scan_kv_in_place(params, h, cfg, apply_fn, cache):
+def _scan_kv_in_place(params, h, apply_fn, cache):
     """Decode over an (L, ...)-stacked block group whose stacked KV cache is
     carried in the loop state: layer i writes only its new positions and
     reads its slice in place, where xs/ys would copy each layer's whole K/V
@@ -214,8 +208,7 @@ def _scan_kv_in_place(params, h, cfg, apply_fn, cache):
     L = jax.tree.leaves(params)[0].shape[0]
     (h, aux, cache), _ = jax.lax.scan(
         body, (h, jnp.zeros((), jnp.float32), cache),
-        (params, jnp.arange(L, dtype=jnp.int32)), length=L,
-        unroll=cfg.scan_unroll)
+        (params, jnp.arange(L, dtype=jnp.int32)), length=L)
     return h, cache, aux
 
 
@@ -246,7 +239,7 @@ def run_stack(params, h, cfg: ModelConfig, *, cos, sin, cache=None,
         def app_decode(p, x, c, i):
             return apply_dense_block(p, x, cfg, cos=cos, sin=sin, cache=c,
                                      cur_len=cur_len, layer=i)
-        return _scan_kv_in_place(params["uniform"], h, cfg, app_decode, cache)
+        return _scan_kv_in_place(params["uniform"], h, app_decode, cache)
 
     def app(p, x, c):
         return apply_dense_block(p, x, cfg, cos=cos, sin=sin, cache=c,
@@ -287,7 +280,7 @@ def _run_local_global_stack(params, h, cfg, *, cos, sin, cache, cur_len,
             lambda t: t.reshape((n_steps, per) + t.shape[1:]), cache)
     (h, aux), new_cache = jax.lax.scan(
         body, (h, jnp.zeros((), jnp.float32)), (params["lg"], c_in),
-        length=n_steps, unroll=cfg.scan_unroll)
+        length=n_steps)
     if new_cache is not None:
         new_cache = jax.tree.map(
             lambda t: t.reshape((cfg.num_layers,) + t.shape[2:]), new_cache)
@@ -340,8 +333,7 @@ def _run_zamba_stack(params, h, cfg, *, cos, sin, cache, cur_len,
 
     (h, aux), (new_m, new_a) = jax.lax.scan(
         group_body, (h, jnp.zeros((), jnp.float32)),
-        (params["mamba_main"], c_mamba_main, c_attn), length=groups,
-        unroll=cfg.scan_unroll)
+        (params["mamba_main"], c_mamba_main, c_attn), length=groups)
 
     new_mamba = jax.tree.map(
         lambda t: t.reshape((groups * ae,) + t.shape[2:]), new_m)

@@ -1,1 +1,1 @@
-from repro.optim import adamw, clip, compress, schedules  # noqa: F401
+from repro.optim import adamw, clip, schedules  # noqa: F401

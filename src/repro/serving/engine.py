@@ -2,7 +2,7 @@
 
 Requests are grouped into equal-prompt-length micro-batches (bucketed
 continuous batching; per-row ragged prompts would need scatter cache
-writes -- see DESIGN.md simplifications).  The engine jits one prefill and
+writes, which this engine does not do).  The engine jits one prefill and
 one decode program per (batch, prompt_len) bucket and reuses them across
 calls (the warm-executable cache that plays the role of the paper's warm
 Python workers).
@@ -18,16 +18,13 @@ Two ways to drive it:
   surviving rows into a smaller batch bucket (slot reuse) so retired
   sequences stop costing decode FLOPs.
 
-Timing honesty: the first ``generate`` call for a given (batch,
-prompt_len, max_new) shape triggers XLA compilation, and jax dispatch is
-asynchronous -- so the stop-clock only runs after ``block_until_ready``,
-and a first-per-shape call's wall goes to ``stats["compile_wall"]``
-(warmup), not ``stats["wall"]``.  ``throughput()`` is therefore
-steady-state tokens/sec over warm executables only.
+The engine keeps no clock.  The first call for a new batch shape
+compiles, and jax dispatch is asynchronous, so whoever times it must
+block on the result and count a shape's first call as warmup; the chip
+benchmark times the served path itself.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional, Sequence
 
 import jax
@@ -93,9 +90,6 @@ class Engine:
         self._prefill = jax.jit(prefill)
         # the cache is donated: a step writes its new positions in place
         self._decode = jax.jit(decode_step, donate_argnums=1)
-        self._warm: set = set()   # (B, S, max_new) shapes already compiled
-        self.stats = {"prefill_calls": 0, "wall": 0.0, "compile_wall": 0.0,
-                      "warm_tokens": 0}
 
     def device_info(self) -> dict:
         """The device holding this engine's weights, as JAX reports it."""
@@ -125,7 +119,6 @@ class Engine:
             logits, cache = self._prefill(self.params, batch)
             with obs.region("engine.grow_cache"):
                 cache = api.grow_cache(self.cfg, cache, reserve)
-            self.stats["prefill_calls"] += 1
             first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             state = GenState(cache=cache, cur=first[:, None], pos=S,
                              reserve=reserve, padded_b=B)
@@ -169,31 +162,12 @@ class Engine:
     def generate(self, tokens: np.ndarray, *, max_new: Optional[int] = None,
                  frames: Optional[np.ndarray] = None) -> np.ndarray:
         """tokens (B, S) equal-length prompts -> (B, S + max_new)."""
-        t_start = time.perf_counter()
         max_new = max_new or self.max_new
-        B, S = tokens.shape
-        first, state = self.prefill_batch(tokens, reserve=S + max_new,
-                                          frames=frames)
+        _, state = self.prefill_batch(
+            tokens, reserve=tokens.shape[1] + max_new, frames=frames)
         out = [state.cur[:, 0]]
         for _ in range(max_new - 1):
             self.decode_batch(state)
             out.append(state.cur[:, 0])
         gen = jnp.stack(out, axis=1)
-        # the stop-clock only runs once the device is done -- without the
-        # sync, async dispatch would make throughput() a dispatch rate
-        gen = jax.block_until_ready(gen)
-        elapsed = time.perf_counter() - t_start
-        key = (B, S, max_new)
-        if key in self._warm:
-            self.stats["wall"] += elapsed
-            self.stats["warm_tokens"] += int(B * max_new)
-        else:
-            self._warm.add(key)
-            self.stats["compile_wall"] += elapsed
         return np.concatenate([tokens, np.asarray(gen)], axis=1)
-
-    def throughput(self) -> float:
-        """Steady-state tokens/sec: warm-executable calls only (first
-        call per shape is compile-dominated and counted in
-        ``stats["compile_wall"]``)."""
-        return self.stats["warm_tokens"] / max(self.stats["wall"], 1e-9)

@@ -12,7 +12,7 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import sys; sys.path.insert(0, 'src')
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
-from repro.configs.base import get_config, SHAPES, ShardingConfig, TrainConfig
+from repro.configs.base import get_config, ShardingConfig, TrainConfig
 from repro.distributed import axisenv, sharding as shd
 from repro.models import api, moe
 from repro.launch import steps
@@ -55,11 +55,8 @@ cfg2 = get_config('internlm2-1.8b', reduced=True).replace(remat='none')
 tc = TrainConfig(lr=1e-3, warmup_steps=0, total_steps=10)
 for mode in ('dp_tp', 'dp_only', 'fsdp_tp'):
     sc = ShardingConfig(mode=mode)
-    shape = SHAPES['train_4k']
-    import dataclasses
-    shape = dataclasses.replace(shape, seq_len=64, global_batch=8)
     with mesh:
-        jfn, args = steps.build_program(cfg2, shape, mesh, tc=tc, sc=sc)
+        jfn, args = steps.build_program(cfg2, 8, 64, mesh, tc=tc, sc=sc)
         state = steps.init_state(cfg2, jax.random.PRNGKey(0))
         batch = {
             'tokens': jnp.zeros((8, 64), jnp.int32),

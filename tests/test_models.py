@@ -1,6 +1,10 @@
 """Per-architecture smoke tests: reduced config, one forward + train step on
 CPU, asserting output shapes + finiteness; plus incremental-decoding
 consistency (prefill + decode_step == full forward)."""
+import ast
+import dataclasses
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -143,3 +147,19 @@ def test_prefill_matches_float32_reference(dtype, tol):
         ref = np.asarray(reference.last_logits(params, cfg, toks))
     err = np.max(np.abs(np.asarray(logits, np.float32) - ref))
     assert err <= tol * np.max(np.abs(ref)), err
+
+
+def test_every_config_field_is_read():
+    """Every field of the model, sharding and train configs is read as an
+    attribute somewhere under src/repro: an option nothing reads is dead
+    weight every new model threads through."""
+    from repro.configs.base import ModelConfig, ShardingConfig, TrainConfig
+    root = pathlib.Path(api.__file__).resolve().parents[1]
+    read = {node.attr for path in root.rglob("*.py")
+            for node in ast.walk(ast.parse(path.read_text()))
+            if isinstance(node, ast.Attribute)
+            and isinstance(node.ctx, ast.Load)}
+    unread = [f"{c.__name__}.{f.name}"
+              for c in (ModelConfig, ShardingConfig, TrainConfig)
+              for f in dataclasses.fields(c) if f.name not in read]
+    assert not unread, unread

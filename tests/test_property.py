@@ -12,7 +12,6 @@ from repro.core import ResourceTracker
 from repro.core.value_server import Proxy, ValueServer
 from repro.kernels.mamba2_ssd import ref as ssd_ref
 from repro.models.attention import mha_reference
-from repro.optim.compress import dequantize_int8, quantize_int8
 
 SETTINGS = dict(max_examples=20, deadline=None)
 
@@ -114,42 +113,6 @@ def test_ssd_linearity_in_x(seed):
     y12, _ = ssd_ref.ssd_naive(x1 + 2.0 * x2, la, b, c)
     np.testing.assert_allclose(np.asarray(y12), np.asarray(y1 + 2.0 * y2),
                                rtol=1e-4, atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# compression invariants
-# ---------------------------------------------------------------------------
-
-@settings(**SETTINGS)
-@given(seed=st.integers(0, 2**16), scale=st.floats(1e-3, 1e3))
-def test_int8_quantization_error_bound(seed, scale):
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal(257) * scale, jnp.float32)
-    q, s = quantize_int8(x)
-    back = dequantize_int8(q, s)
-    # error bounded by half a quantization step
-    max_err = float(jnp.max(jnp.abs(back - x)))
-    assert max_err <= float(s) * 0.5 + 1e-6
-
-
-@settings(**SETTINGS)
-@given(seed=st.integers(0, 2**16))
-def test_error_feedback_accumulates_to_truth(seed):
-    """With error feedback, the *sum* of dequantized transmissions
-    converges to the sum of the true gradients."""
-    from repro.optim.compress import compress_tree
-    rng = np.random.default_rng(seed)
-    true_sum = np.zeros(64, np.float32)
-    sent_sum = np.zeros(64, np.float32)
-    errors = None
-    for _ in range(30):
-        g = jnp.asarray(rng.standard_normal(64), jnp.float32) * 0.1
-        true_sum += np.asarray(g)
-        payload, errors = compress_tree(g, "int8_ef", errors)
-        sent_sum += np.asarray(dequantize_int8(*payload))
-    resid = np.abs(true_sum - sent_sum)
-    # residual equals the current error-feedback buffer -> bounded by one step
-    assert np.max(resid) < 0.05
 
 
 # ---------------------------------------------------------------------------

@@ -47,13 +47,8 @@ def test_engine_ssm_arch():
         0, cfg.vocab_size, size=(2, 8), dtype=np.int32)
     out = eng.generate(prompts)
     assert out.shape == (2, 12)
-    # first call per shape is compile-dominated: it counts as warmup,
-    # not steady-state throughput
-    assert eng.throughput() == 0
-    assert eng.stats["compile_wall"] > 0
-    eng.generate(prompts)
-    assert eng.throughput() > 0
-    assert eng.stats["wall"] > 0
+    # a second call reuses the compiled bucket and is deterministic
+    np.testing.assert_array_equal(eng.generate(prompts), out)
 
 
 def test_engine_stepwise_matches_generate():
